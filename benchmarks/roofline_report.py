@@ -23,19 +23,22 @@ from __future__ import annotations
 import json
 import os
 
+from repro.launch.hlo_analysis import V5E
+
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun.json")
 
 
-def lut_decomposition(arch: str, tokens: int) -> dict:
+def lut_decomposition(arch: str, tokens: int, device_kind: str = V5E) -> dict:
     """Analytic gather-vs-accumulate split of ``tokens`` decode tokens
     through ``arch``'s LUT-converted projections (uniform chunk-1 plan, the
     dryrun's conversion).  Gather is HBM-bound (bytes of table rows
     touched), accumulate is compute-bound (one shift-add per gathered
-    element); both in seconds at the chip peaks ``hlo_analysis`` uses."""
+    element); both in seconds at ``device_kind``'s published peaks
+    (``hlo_analysis.PEAKS``)."""
     from repro.configs.base import get_config
     from repro.core.lut import plane_scales
     from repro.core.planner import plan_model
-    from repro.launch.hlo_analysis import HBM_BW, PEAK_FLOPS
+    from repro.launch.hlo_analysis import peaks
     from repro.models.model import model_specs
     from repro.models.params import abstract_params
 
@@ -52,8 +55,9 @@ def lut_decomposition(arch: str, tokens: int) -> dict:
     return {
         "gather_bytes": tokens * gather_bytes,
         "accumulate_ops": tokens * accum_ops,
-        "gather_s": tokens * gather_bytes / HBM_BW,
-        "accumulate_s": tokens * accum_ops / PEAK_FLOPS,
+        "gather_s": tokens * gather_bytes / peaks(device_kind)["hbm_bw"],
+        "accumulate_s": tokens * accum_ops / peaks(device_kind)["bf16_flops"],
+        "device_kind": device_kind,
     }
 
 

@@ -80,8 +80,10 @@ def main():
         steps += 1
     dt = time.perf_counter() - t0
     total = sum(len(r.generated) for r in reqs)
+    dev = jax.devices()[0]
     print(f"{len(reqs)} requests on {args.slots} slots: {steps} decode steps, "
-          f"{total} tokens in {dt:.1f}s ({total / dt:.1f} tok/s, CPU oracle; "
+          f"{total} tokens in {dt:.1f}s ({total / dt:.1f} tok/s on "
+          f"{dev.platform} {dev.device_kind}, jnp oracle; "
           f"{eng.readbacks} host readbacks)")
     for r in reqs:
         print(f"  req {r.uid}: prompt {list(map(int, r.prompt))} -> {r.generated}")

@@ -8,7 +8,7 @@ of model configs x table families and checking rules on the closed jaxpr
 and compiled HLO, with nothing executed:
 
 * :func:`iter_eqns` / :func:`op_census` — the one recursive jaxpr walker
-  (scan/while/cond/pjit/custom-vjp/remat; ``pallas_call`` stays opaque)
+  (scan/while/cond/jit/custom-vjp/remat; ``pallas_call`` stays opaque)
 * :func:`multiplier_free_violations`, :func:`zero_copy_violations`,
   :func:`plan_consistency_violations`, :func:`donation_violations`,
   :func:`overflow_violations` — the rule classes (empty list == invariant

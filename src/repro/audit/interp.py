@@ -37,7 +37,8 @@ import dataclasses
 import math
 
 import numpy as np
-from jax import core as jax_core
+from jax._src.core import DropVar  # jax.extend.core does not export it
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.audit.walker import OPAQUE_PRIMITIVES
 
@@ -452,7 +453,7 @@ _FLAGGED = frozenset(
 
 _CALL_PRIMS = frozenset(
     {
-        "pjit",
+        "jit",
         "closed_call",
         "core_call",
         "remat",
@@ -466,10 +467,10 @@ _CALL_PRIMS = frozenset(
 
 
 def _sub_jaxpr(v):
-    if isinstance(v, jax_core.ClosedJaxpr):
+    if isinstance(v, ClosedJaxpr):
         return v
-    if isinstance(v, jax_core.Jaxpr):
-        return jax_core.ClosedJaxpr(v, ())
+    if isinstance(v, Jaxpr):
+        return ClosedJaxpr(v, ())
     return None
 
 
@@ -499,13 +500,13 @@ class _Interp:
 
     # -- env ----------------------------------------------------------------
     def _read(self, env, v) -> Interval:
-        if isinstance(v, jax_core.Literal):
+        if isinstance(v, Literal):
             return _const_interval(v.val)
         got = env.get(v)
         return got if got is not None else dtype_interval(v.aval.dtype)
 
     def _write(self, env, v, ideal: Interval, name: str):
-        if isinstance(v, jax_core.DropVar):
+        if isinstance(v, DropVar):
             return
         d = np.dtype(v.aval.dtype)
         machine = dtype_interval(d)

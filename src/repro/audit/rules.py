@@ -188,7 +188,7 @@ def plan_consistency_violations(mplan, tree, *, batch: int = 1) -> list[Violatio
     * the bytes actually materialised across table leaves equal
       ``mplan.total_lut_bytes`` (the PR 5 copies accounting);
     * any tuned ``blocks`` riding a plan are legal under the kernels'
-      4 MiB VMEM budget (``kernels.lut_affine.autotune.blocks_fit_vmem``).
+      tiling and VMEM budget (``kernels.lut_affine.autotune.blocks_fit_vmem``).
     """
     from repro.core.convert import LUTGroup, LUTLinear
     from repro.core.planner import path_key

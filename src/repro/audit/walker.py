@@ -3,7 +3,7 @@
 One walker for every structural audit in the repo (the rule classes in
 ``audit.rules`` and the jaxpr-level acceptance tests).  It descends into
 every sub-jaxpr an equation carries in its params — ``scan`` / ``while`` /
-``cond`` branches, ``pjit``, ``custom_jvp``/``custom_vjp`` callables,
+``cond`` branches, ``jit``, ``custom_jvp``/``custom_vjp`` callables,
 ``remat`` (``checkpoint``) bodies — because all of them store their bodies
 as ``Jaxpr`` / ``ClosedJaxpr`` values (possibly inside lists or tuples).
 
@@ -19,7 +19,7 @@ from __future__ import annotations
 from collections import Counter
 from typing import Iterator
 
-from jax import core as jax_core
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 # Primitives surfaced as opaque leaves: yielded, never descended into.
 OPAQUE_PRIMITIVES = frozenset({"pallas_call"})
@@ -32,7 +32,7 @@ def iter_eqns(jaxpr) -> Iterator:
     the latter).  Equations whose primitive is in :data:`OPAQUE_PRIMITIVES`
     are yielded but not descended into.
     """
-    if isinstance(jaxpr, jax_core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -41,7 +41,7 @@ def iter_eqns(jaxpr) -> Iterator:
         for v in eqn.params.values():
             sub = v if isinstance(v, (list, tuple)) else (v,)
             for s in sub:
-                if isinstance(s, (jax_core.ClosedJaxpr, jax_core.Jaxpr)):
+                if isinstance(s, (ClosedJaxpr, Jaxpr)):
                     yield from iter_eqns(s)
 
 

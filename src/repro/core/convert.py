@@ -218,8 +218,28 @@ def group_key(members: tuple) -> str:
     return "+".join(members)
 
 
+def _per_set(build, ws: list, lead: int):
+    """``build(ws) -> (tables, scale)`` one leading (layer) index at a time
+    when the weights carry ``lead`` leading scan dims, stacking the results.
+    ``build`` runs jitted, so its fp32 intermediates fuse, and only one
+    set's are alive at once: a model-width stack converts within device
+    memory.  Identical to building the whole stack (every table set and its
+    scale depend on that set's weights only)."""
+    build = jax.jit(build)
+
+    def sets(ws, lead):
+        if lead == 0:
+            return build(ws)
+        parts = [sets([w[i] for w in ws], lead - 1) for i in range(ws[0].shape[0])]
+        tables = jnp.stack([t for t, _ in parts])
+        scale = None if parts[0][1] is None else jnp.stack([s for _, s in parts])
+        return tables, scale
+
+    return sets(ws, lead)
+
+
 def _build_tables(w, plan: LUTPlan, dtype):
-    """build_luts vmapped over any leading (layer/expert) dims."""
+    """build_luts vmapped over any leading (expert) dims."""
 
     def fn(m):
         return build_luts(m.astype(jnp.float32), plan)
@@ -230,7 +250,7 @@ def _build_tables(w, plan: LUTPlan, dtype):
 
 
 def _build_tl1(w):
-    """build_tl1_tables vmapped over any leading (layer/expert) dims.
+    """build_tl1_tables vmapped over any leading (expert) dims.
 
     Returns ``(packed (..., kb, p) uint8, scale (...) f32)`` — one ternary
     scale per weight matrix, shaped like the leading dims."""
@@ -306,12 +326,14 @@ def convert_params(
 
     def convert_one(node: dict, layer_plan: AnyPlan, expert: bool = False) -> LUTLinear:
         w = node["w"]
-        if isinstance(layer_plan, TL1Plan):
-            tables, scale = _build_tl1(w)
-        else:
-            tables, scale = finalize_tables(
-                _build_tables(w, layer_plan, jnp.float32), layer_plan, 3 + expert
-            )
+
+        def build(ws):
+            if isinstance(layer_plan, TL1Plan):
+                return _build_tl1(ws[0])
+            tables = _build_tables(ws[0], layer_plan, jnp.float32)
+            return finalize_tables(tables, layer_plan, 3 + expert)
+
+        tables, scale = _per_set(build, [w], w.ndim - 2 - expert)
         stats["converted"] += 1
         stats["w_bytes"] += w.size * w.dtype.itemsize
         stats["t_bytes"] += tables.size * tables.dtype.itemsize
@@ -341,22 +363,28 @@ def convert_params(
                 f"mismatched member plans — grouped siblings must share one"
             )
         if isinstance(plans[0], TL1Plan):
-            built = [_build_tl1(node[m]["w"]) for m in members]
-            # stack G just before the packed-chunk axis: (..., G, kb, p);
-            # ternary scales are per member, stacked to (..., G)
-            tables = jnp.stack([t for t, _ in built], axis=built[0][0].ndim - 2)
-            scale = jnp.stack([s for _, s in built], axis=-1)
+
+            def build(ws):
+                built = [_build_tl1(w) for w in ws]
+                # stack G just before the packed-chunk axis: (..., G, kb, p);
+                # ternary scales are per member, stacked to (..., G)
+                tables = jnp.stack([t for t, _ in built], axis=built[0][0].ndim - 2)
+                return tables, jnp.stack([s for _, s in built], axis=-1)
+
         else:
-            member_tables = [
-                _build_tables(node[m]["w"], plans[0], jnp.float32) for m in members
-            ]
-            # quantize the STACKED leaf as one, so the whole group shares one
-            # dequant scale (the group executes as a single fused dispatch)
-            tables, scale = finalize_tables(
-                jnp.stack(member_tables, axis=member_tables[0].ndim - 3),
-                plans[0],
-                4 + expert,
-            )
+
+            def build(ws):
+                member_tables = [_build_tables(w, plans[0], jnp.float32) for w in ws]
+                # quantize the STACKED leaf as one, so the whole group shares
+                # one dequant scale (the group executes as a single dispatch)
+                return finalize_tables(
+                    jnp.stack(member_tables, axis=member_tables[0].ndim - 3),
+                    plans[0],
+                    4 + expert,
+                )
+
+        ws = [node[m]["w"] for m in members]
+        tables, scale = _per_set(build, ws, ws[0].ndim - 2 - expert)
         stats["converted"] += len(members)
         for m in members:
             w = node[m]["w"]

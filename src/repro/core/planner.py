@@ -450,6 +450,7 @@ def plan_model(
     families: Sequence[str] = ("weight",),
     tl1_act_bits: int | None = 8,
     tl1_acc_dtype: str = "int32",
+    max_entries: int | None = None,
 ) -> ModelPlan:
     """Choose a per-layer plan for every eligible linear under a global budget.
 
@@ -500,8 +501,13 @@ def plan_model(
     stamped with the proved bound (``max_abs_acc``) so kernels can assert
     the contract at trace time and checkpoints carry the proof.
 
+    ``max_entries`` drops weight-table candidates with more entries per
+    table than that — pass ``kernels.common.MAX_SELECT_ENTRIES`` to plan
+    for the Pallas kernels, which raise on wider tables.
+
     Raises ``ValueError`` if even the minimal per-layer plans exceed
-    ``max_lut_bytes``, or if every candidate for some layer fails its
+    ``max_lut_bytes``, if no candidate of some layer has at most
+    ``max_entries`` entries, or if every candidate for some layer fails its
     accumulator certificate.
     """
     # call-time import: repro.audit imports this module (points builds
@@ -561,6 +567,7 @@ def plan_model(
                         max_chunk=max_chunk,
                         table_formats=table_formats,
                     )
+                    if max_entries is None or pt.plan.num_entries <= max_entries
                 ]
             if "tl1" in families:
                 pts.append(

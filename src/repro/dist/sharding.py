@@ -141,3 +141,41 @@ class ShardCtx:
             x,
             NamedSharding(self.mesh, self.spec(axes, x.shape)),
         )
+
+
+def converted_shardings(converted, dense, shard: ShardCtx, fsdp_tables: bool = False):
+    """Shardings for a TableNet-converted tree (arrays or shape stand-ins).
+
+    ``dense`` is the unconverted tree with ``.sharding`` on each leaf (e.g.
+    ``abstract_params(..., sharding_fn=shard.param_sharding)``).  Leaves
+    whose path and shape coincide with a dense leaf (embedding, norms,
+    biases) keep its sharding.  ``tables`` leaves — weight tables
+    ``(..., k, entries, p)`` and TL1 packed indices ``(..., kb, p)`` — are
+    sharded on their output dim ``p`` over ``model``, like the weights they
+    replace; with ``fsdp_tables`` the weight tables' chunk dim also goes
+    over ``data`` (ZeRO-3 tables).  Everything else is replicated.
+    """
+    assert shard.mesh is not None, "converted_shardings needs a mesh"
+    dense_flat = {
+        jax.tree_util.keystr(p): leaf
+        for p, leaf in jax.tree_util.tree_flatten_with_path(dense)[0]
+    }
+
+    def place(path, leaf):
+        same = dense_flat.get(jax.tree_util.keystr(path))
+        if same is not None and same.shape == leaf.shape:
+            return same.sharding
+        # dict levels carry DictKey (.key); LUTLinear/LUTGroup children
+        # carry GetAttrKey (.name)
+        name = getattr(path[-1], "key", None) or getattr(path[-1], "name", None)
+        axes = [None] * leaf.ndim
+        if name == "tables":
+            n_model = shard.axis_size("model")
+            if n_model and leaf.shape[-1] % n_model == 0:
+                axes[-1] = "model"
+            if fsdp_tables and leaf.ndim >= 3:
+                if leaf.shape[-3] % max(shard.axis_size("data"), 1) == 0:
+                    axes[-3] = "data"
+        return NamedSharding(shard.mesh, P(*axes))
+
+    return jax.tree_util.tree_map_with_path(place, converted)

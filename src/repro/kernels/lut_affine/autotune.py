@@ -1,6 +1,6 @@
 """Autotuner for LUT-affine Pallas block shapes.
 
-``ops._pick_blocks`` is a one-shot heuristic: it maximises the chunk tile
+:func:`pick_blocks` is a one-shot heuristic: it maximises the chunk tile
 under a VMEM budget and fixes the batch tile at ``min(B, 128)``.  That is a
 fine default, but the best ``(block_b, block_p, block_k)`` tiling depends on
 the *shape point* a dispatch actually presents — decode batch, chunk count,
@@ -22,8 +22,10 @@ Two search modes:
   Fully reproducible across hosts, so CI can re-search the committed
   baseline points and fail on drift (``python -m
   repro.kernels.lut_affine.autotune check``).
-* ``measured`` — wall-clock the real kernel (interpret mode off-TPU) over
-  the candidate set.  Slower and machine-dependent; for hand tuning, not CI.
+* ``measured`` — wall-clock the compiled kernel on a TPU over the
+  candidate set (it raises on any other backend: interpreter timings say
+  nothing about the chip).  Slower and machine-dependent; for hand tuning,
+  not CI.
 """
 from __future__ import annotations
 
@@ -43,7 +45,10 @@ _STEP_OVERHEAD = 4096.0
 _DMA = 1.0
 _FMA = 0.25
 
-_VMEM_BUDGET = 4 * 2**20  # keep in lock-step with ops._VMEM_BUDGET
+# Bytes of double-buffered blocks one grid step may hold (``vmem_bytes``);
+# half of the 16 MiB scoped VMEM a v5e kernel gets by default, leaving room
+# for the in-kernel fp32 accumulators.
+VMEM_BUDGET = 8 * 2**20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,47 +109,89 @@ class TunePoint:
         )
 
 
-def table_tile_bytes(pt: TunePoint, blocks: tuple[int, int, int]) -> int:
-    """Live table-tile bytes a ``blocks`` tiling keeps resident for ``pt``,
-    with the same ``G``-aware accounting as ``ops._pick_blocks``."""
-    _, bp, bk = blocks
+def block_k_options(k: int, family: str = "weight") -> list[int]:
+    """Legal chunk tiles for a ``k``-chunk axis.  The chunk tile is the lane
+    axis of the codes block, so Mosaic takes a multiple of 128 (the chunk
+    axis is then zero-padded up to it) or the whole axis when ``k <= 128``.
+    TL1 reads its uint8 packed rows in whole 32-row tiles, so there a short
+    axis is padded to a multiple of 32 first."""
+    if k <= 128:
+        return [ceil_to(k, 32) if family == "tl1" else k]
+    opts, bk = [], 128
+    while bk <= ceil_to(k, 128):
+        opts.append(bk)
+        bk *= 2
+    return opts
+
+
+def vmem_bytes(pt: TunePoint, blocks: tuple[int, int, int]) -> int:
+    """VMEM one grid step holds: every block double-buffered, each padded
+    to its dtype's (sublane, 128) tile (8 rows for 4-byte, 16 for 2-byte,
+    32 for 1-byte elements).  ``G`` does not multiply it: the grouped grid
+    walks the group axis, one member's table tile at a time."""
+    bb, bp, bk = blocks
+    sublanes = 32 // pt.table_bytes
+    codes = (4 if pt.family == "tl1" else pt.n) * bb * ceil_to(bk, 128) * 4
     if pt.family == "tl1":
-        # the packed-index tile is plain bytes — no entries axis
-        return pt.G * bk * bp * pt.table_bytes
-    return pt.G * bk * pt.entries * bp * pt.table_bytes
+        tables = ceil_to(bk, sublanes) * bp * pt.table_bytes  # packed bytes
+    else:
+        tables = bk * ceil_to(pt.entries, sublanes) * bp * pt.table_bytes
+    out = bb * bp * 4
+    return 2 * (codes + tables + out)
 
 
 def blocks_fit_vmem(pt: TunePoint, blocks: tuple[int, int, int]) -> bool:
-    """Whether a tiling's live table tile fits the kernels' VMEM budget.
+    """Whether a tiling obeys the kernels' (8, 128) tiling for ``pt`` and
+    fits their VMEM budget.
 
     The reusable legality predicate: ``candidate_blocks`` enumerates with
-    it, and ``repro.audit``'s plan-consistency rule re-checks any ``blocks``
-    riding a ``ModelPlan`` against the same budget.
+    it, :func:`pick_blocks` picks under it, and ``repro.audit``'s
+    plan-consistency rule re-checks any ``blocks`` riding a ``ModelPlan``.
     """
-    return table_tile_bytes(pt, tuple(blocks)) <= _VMEM_BUDGET
+    bb, bp, bk = blocks = tuple(blocks)
+    tiled = bb % 8 == 0 and bp % 128 == 0 and bk in block_k_options(pt.k, pt.family)
+    return tiled and vmem_bytes(pt, blocks) <= VMEM_BUDGET
 
 
 def candidate_blocks(pt: TunePoint) -> list[tuple[int, int, int]]:
-    """All legal ``(block_b, block_p, block_k)`` tilings for ``pt``.
-
-    Legality mirrors the kernel's constraints: the batch tile is a multiple
-    of 8 (sublane), the output tile a multiple of 128 (lane), the chunk tile
-    a power of two, and the live table tiles fit the VMEM budget
-    (:func:`blocks_fit_vmem`).
-    """
+    """All legal ``(block_b, block_p, block_k)`` tilings for ``pt``:
+    batch tiles of 8..128 rows, output tiles of 128..512 lanes, the chunk
+    tiles of :func:`block_k_options`, within :func:`blocks_fit_vmem`."""
     bbs = [bb for bb in (8, 16, 32, 64, 128) if bb <= ceil_to(pt.B, 8) * 2]
     bps = [bp for bp in (128, 256, 512) if bp <= ceil_to(pt.p, 128)]
-    bks, bk = [], 1
-    while bk <= pt.k:
-        bks.append(bk)
-        bk *= 2
     return [
         (bb, bp, bk)
         for bb in bbs
         for bp in bps
-        for bk in bks
+        for bk in block_k_options(pt.k, pt.family)
         if blocks_fit_vmem(pt, (bb, bp, bk))
     ]
+
+
+def check_blocks(pt: TunePoint, blocks) -> tuple[int, int, int]:
+    """``blocks`` as a tuple, or ValueError when it breaks the tiling or the
+    VMEM budget (Mosaic would refuse it; the interpreter would not)."""
+    blocks = tuple(int(v) for v in blocks)
+    if not blocks_fit_vmem(pt, blocks):
+        raise ValueError(
+            f"blocks {blocks} are not a legal tiling of {pt}: need batch % 8, "
+            f"out % 128, chunk tile in {block_k_options(pt.k, pt.family)}, "
+            f"and at most {VMEM_BUDGET} bytes of VMEM "
+            f"(this takes {vmem_bytes(pt, blocks)})"
+        )
+    return blocks
+
+
+def pick_blocks(pt: TunePoint) -> tuple[int, int, int]:
+    """The untuned default tiling: the batch tile covers the batch (up to
+    128 rows), then the widest output tile and the largest chunk tile that
+    fit the budget.  Raises when even the smallest tiles do not fit."""
+    bb = min(ceil_to(pt.B, 8), 128)
+    for bp in [bp for bp in (512, 256, 128) if bp <= ceil_to(pt.p, 128)]:
+        for bk in reversed(block_k_options(pt.k, pt.family)):
+            if blocks_fit_vmem(pt, (bb, bp, bk)):
+                return bb, bp, bk
+    raise ValueError(f"no tiling of {pt} fits the {VMEM_BUDGET}-byte VMEM budget")
 
 
 def analytic_cost(pt: TunePoint, blocks: tuple[int, int, int]) -> float:
@@ -173,9 +220,15 @@ def analytic_cost(pt: TunePoint, blocks: tuple[int, int, int]) -> float:
 
 
 def _measure(pt: TunePoint, blocks: tuple[int, int, int], reps: int = 5) -> float:
-    """Median wall-clock seconds of the real (or interpreted) kernel."""
+    """Median wall-clock seconds of the compiled kernel on the TPU."""
     import jax
     import jax.numpy as jnp
+
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"measured autotuning times the TPU kernel; backend is "
+            f"{jax.default_backend()!r}"
+        )
 
     from repro.kernels.lut_affine.ops import lut_affine, lut_affine_grouped
 

@@ -13,37 +13,33 @@ import jax.numpy as jnp
 from repro.kernels.common import (
     ceil_to,
     check_acc_contract,
+    check_entries,
+    code_lanes,
     default_interpret,
     pad_axis,
 )
+from repro.kernels.lut_affine.autotune import TunePoint, check_blocks, pick_blocks
 from repro.kernels.lut_affine.lut_affine import (
     lut_affine_experts_pallas,
     lut_affine_grouped_pallas,
     lut_affine_pallas,
 )
 
-_VMEM_BUDGET = 4 * 2**20  # bytes of live blocks per grid step
+def _tiling(blocks, B: int, k: int, E: int, p: int, n: int, G: int, tables):
+    """``blocks`` checked against the kernels' tiling and VMEM rules, or the
+    untuned default (``autotune.pick_blocks``) when ``blocks`` is None."""
+    pt = TunePoint(
+        B=B, k=k, entries=E, p=p, n=n, G=G, table_bytes=tables.dtype.itemsize
+    )
+    return check_blocks(pt, blocks) if blocks else pick_blocks(pt)
 
 
-def _pick_blocks(B: int, k: int, E: int, p: int, n: int, G: int = 1):
-    """Block sizes keeping live table tiles under ``_VMEM_BUDGET``.
-
-    ``G`` is the group dimension of :func:`lut_affine_grouped`: grouped
-    dispatches keep ``G`` projections' table tiles in flight across the
-    group-major grid, so the budget accounting scales by ``G`` (omitting it
-    let grouped blocks exceed the budget by up to ``G``x).
-    """
-    block_p = min(ceil_to(p, 128), 512)
-    # tables dominate VMEM: G * kb * E * pb * 4 <= budget.  Shrink in
-    # 128-multiples only — Mosaic needs lane-dim blocks of 128.
-    while block_p > 128 and G * E * block_p * 4 > _VMEM_BUDGET:
-        block_p = max(128, (block_p // 2 + 127) // 128 * 128)
-    block_b = min(ceil_to(B, 8), 128)
-    max_kb = max(1, _VMEM_BUDGET // (G * E * block_p * 4))
-    block_k = 1
-    while block_k * 2 <= min(max_kb, k):
-        block_k *= 2
-    return block_b, block_p, block_k
+def _plane_major(codes2: jax.Array, rows: int, chunks: int, block_k: int):
+    """(B, n, k) codes -> zero-padded (n, rows, code_lanes), the kernels'
+    layout (padded chunks index entry 0 of a zero table -> contribute
+    nothing; lanes past a narrow tile's chunks are never read)."""
+    codes2 = jnp.swapaxes(codes2, 0, 1)
+    return pad_axis(pad_axis(codes2, 1, rows), 2, code_lanes(chunks, block_k))
 
 
 @functools.partial(
@@ -78,7 +74,7 @@ def lut_affine(
 ) -> jax.Array:
     """out[..., :] = sum_j scales[j] * sum_c tables[c, codes[..., j, c], :] + bias
 
-    ``blocks`` overrides the static ``_pick_blocks`` heuristic with autotuned
+    ``blocks`` overrides the default ``autotune.pick_blocks`` tiling with autotuned
     ``(block_b, block_p, block_k)`` tile sizes (see ``autotune.py``);
     ``shift_bits`` selects the ``bitplane_shift`` code contract; ``plan``
     (a ``LUTPlan``) asserts the accumulator contract at trace time when it
@@ -90,15 +86,14 @@ def lut_affine(
     *lead, n, k = codes.shape
     k2, E, p = tables.shape
     assert k == k2, f"codes have {k} chunks, tables {k2}"  # before padding
+    check_entries("lut_affine", E)
     B = 1
     for d in lead:
         B *= d
-    codes2 = codes.reshape(B, n, k)
 
-    block_b, block_p, block_k = blocks or _pick_blocks(B, k, E, p, n)
+    block_b, block_p, block_k = _tiling(blocks, B, k, E, p, n, 1, tables)
     Bp, pp, kp = ceil_to(B, block_b), ceil_to(p, block_p), ceil_to(k, block_k)
-    codes2 = pad_axis(pad_axis(codes2, 0, Bp), 2, kp)
-    # padded chunks index entry 0 of a zero table -> contribute nothing
+    codes2 = _plane_major(codes.reshape(B, n, k), Bp, kp, block_k)
     tables_p = pad_axis(pad_axis(tables, 0, kp), 2, pp)
 
     out = _lut_affine_padded(
@@ -152,15 +147,14 @@ def lut_affine_grouped(
     *lead, n, k = codes.shape
     G, k2, E, p = tables.shape
     assert k == k2, f"codes have {k} chunks, tables {k2}"  # before padding
+    check_entries("lut_affine_grouped", E)
     B = 1
     for d in lead:
         B *= d
-    codes2 = codes.reshape(B, n, k)
 
-    block_b, block_p, block_k = blocks or _pick_blocks(B, k, E, p, n, G=G)
+    block_b, block_p, block_k = _tiling(blocks, B, k, E, p, n, G, tables)
     Bp, pp, kp = ceil_to(B, block_b), ceil_to(p, block_p), ceil_to(k, block_k)
-    codes2 = pad_axis(pad_axis(codes2, 0, Bp), 2, kp)
-    # padded chunks index entry 0 of a zero table -> contribute nothing
+    codes2 = _plane_major(codes.reshape(B, n, k), Bp, kp, block_k)
     tables_p = pad_axis(pad_axis(tables, 1, kp), 3, pp)
 
     out = _lut_affine_grouped_padded(
@@ -216,13 +210,13 @@ def lut_affine_experts(
     E, G, k2, En, p = tables.shape
     assert k == k2, f"codes have {k} chunks, tables {k2}"  # before padding
     assert group_sizes.shape == (E,), (group_sizes.shape, E)
+    check_entries("lut_affine_experts", En)
 
-    block_b, block_p, block_k = blocks or _pick_blocks(T, k, En, p, n)
+    block_b, block_p, block_k = _tiling(blocks, T, k, En, p, n, 1, tables)
     Tp, pp, kp = ceil_to(T, block_b), ceil_to(p, block_p), ceil_to(k, block_k)
-    codes2 = pad_axis(pad_axis(codes, 0, Tp), 2, kp)
-    # padded chunks index entry 0 of a zero table -> contribute nothing;
     # padded token rows sit past offsets[-1] -> outside every expert's row
     # range -> left at the kernel's zero init and sliced off below
+    codes2 = _plane_major(codes, Tp, kp, block_k)
     tables_p = pad_axis(pad_axis(tables, 2, kp), 4, pp)
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(group_sizes.astype(jnp.int32))]

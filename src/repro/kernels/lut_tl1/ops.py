@@ -15,40 +15,43 @@ import jax.numpy as jnp
 from repro.kernels.common import (
     ceil_to,
     check_acc_contract,
+    code_lanes,
     default_interpret,
     pad_axis,
 )
+from repro.kernels.lut_affine.autotune import TunePoint, check_blocks, pick_blocks
 from repro.kernels.lut_tl1.lut_tl1 import lut_tl1_grouped_pallas, lut_tl1_pallas
 
-_VMEM_BUDGET = 4 * 2**20  # bytes of live blocks per grid step
+def _tiling(blocks, B: int, kb: int, p: int, G: int):
+    """``blocks`` checked against the kernels' tiling and VMEM rules, or the
+    untuned default (``autotune.pick_blocks``) when ``blocks`` is None.
+    The packed-index tile is ``kb_block * p_block`` BYTES, so block_k
+    usually reaches far along the packed axis."""
+    pt = TunePoint(
+        B=B, k=kb, entries=9, p=p, n=1, G=G, table_bytes=1, family="tl1"
+    )
+    return check_blocks(pt, blocks) if blocks else pick_blocks(pt)
 
 
-def _pick_blocks(B: int, kb: int, p: int, G: int = 1):
-    """Block sizes keeping live tiles under ``_VMEM_BUDGET``.
-
-    The packed-index tile is ``G * kb_block * p_block`` BYTES (uint8) and
-    the activation tile ``bb * 4 * kb_block * 4`` — both tiny next to the
-    weight family's ``entries``-wide tables, so block_k usually reaches the
-    whole packed axis.
-    """
-    block_p = min(ceil_to(p, 128), 512)
-    block_b = min(ceil_to(B, 8), 128)
-    per_k = G * block_p + block_b * 16  # bytes per unit of block_k
-    max_kb = max(1, _VMEM_BUDGET // per_k)
-    block_k = 1
-    while block_k * 2 <= min(max_kb, kb):
-        block_k *= 2
-    return block_b, block_p, block_k
-
-
-def _acts3(acts: jax.Array, kb: int):
-    """(..., 4*kb) flat codes -> (B, 4, kb) kernel tile layout + lead dims."""
+def _acts3(acts: jax.Array, kb: int, rows: int, chunks: int, block_k: int):
+    """(..., 4*kb) flat codes -> zero-padded (4, rows, code_lanes) kernel
+    layout (slot ``j`` of packed byte ``c`` at ``[j, :, c]``) + lead dims.
+    Padded chunk rows meet zero activation codes -> every LUT entry they
+    can index is 0."""
     *lead, q4 = acts.shape
     assert q4 == 4 * kb, (q4, kb)
     B = 1
     for d in lead:
         B *= d
-    return jnp.swapaxes(acts.reshape(B, kb, 4), 1, 2), lead, B
+    acts3 = jnp.transpose(acts.reshape(B, kb, 4), (2, 0, 1))
+    return pad_axis(pad_axis(acts3, 1, rows), 2, code_lanes(chunks, block_k)), lead
+
+
+def _rows(acts: jax.Array) -> int:
+    B = 1
+    for d in acts.shape[:-1]:
+        B *= d
+    return B
 
 
 def _dequant(out, act_scale, scale, bias):
@@ -89,7 +92,7 @@ def lut_tl1(
 ) -> jax.Array:
     """out[..., :] = act_scale * scale * sum_c lut[c, widx[c, :]] + bias
 
-    ``blocks`` overrides the static ``_pick_blocks`` heuristic with autotuned
+    ``blocks`` overrides the default ``autotune.pick_blocks`` tiling with autotuned
     ``(block_b, block_p, block_k)`` tile sizes (block_k in packed bytes);
     ``plan`` (a ``TL1Plan``) asserts the accumulator contract at trace time
     when it carries a proved ``max_abs_acc``."""
@@ -102,13 +105,12 @@ def lut_tl1(
     if interpret is None:
         interpret = default_interpret()
     kb, p = tables.shape
-    acts3, lead, B = _acts3(acts, kb)
+    B = _rows(acts)
 
-    block_b, block_p, block_k = blocks or _pick_blocks(B, kb, p)
+    block_b, block_p, block_k = _tiling(blocks, B, kb, p, 1)
     Bp, pp, kp = ceil_to(B, block_b), ceil_to(p, block_p), ceil_to(kb, block_k)
-    # padded chunk rows meet zero-padded activation codes -> every LUT entry
-    # they can index is 0; padded p columns are sliced off below
-    acts3 = pad_axis(pad_axis(acts3, 0, Bp), 2, kp)
+    acts3, lead = _acts3(acts, kb, Bp, kp, block_k)
+    # padded p columns are sliced off below
     tables_p = pad_axis(pad_axis(tables, 0, kp), 1, pp)
 
     out = _lut_tl1_padded(acts3, tables_p, block_b, block_p, block_k, interpret)
@@ -156,11 +158,11 @@ def lut_tl1_grouped(
     if interpret is None:
         interpret = default_interpret()
     G, kb, p = tables.shape
-    acts3, lead, B = _acts3(acts, kb)
+    B = _rows(acts)
 
-    block_b, block_p, block_k = blocks or _pick_blocks(B, kb, p, G=G)
+    block_b, block_p, block_k = _tiling(blocks, B, kb, p, G)
     Bp, pp, kp = ceil_to(B, block_b), ceil_to(p, block_p), ceil_to(kb, block_k)
-    acts3 = pad_axis(pad_axis(acts3, 0, Bp), 2, kp)
+    acts3, lead = _acts3(acts, kb, Bp, kp, block_k)
     tables_p = pad_axis(pad_axis(tables, 1, kp), 2, pp)
 
     out = _lut_tl1_grouped_padded(

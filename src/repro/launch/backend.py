@@ -115,3 +115,19 @@ def from_args(args) -> BackendConfig:
         host_device_count=args.host_device_count,
         xla_flags=tuple(args.xla_flag),
     )
+
+
+def use_compile_cache(default_dir: str) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads it
+    itself).  Otherwise the cache goes to ``default_dir`` — a fixed path,
+    because the path is part of what a later process must find again.
+    """
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", default_dir)
+    return default_dir

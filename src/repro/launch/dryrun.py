@@ -3,9 +3,11 @@ import os
 
 from repro.launch.backend import BackendConfig
 
-BackendConfig(host_device_count=512).apply()
+BackendConfig(platform="cpu", host_device_count=512).apply()
 # ^^ MUST precede any jax-importing module: jax locks the device count at
-# first init.  Only the dry-run sees 512 placeholder devices.
+# first init.  Only the dry-run sees 512 placeholder devices; pinning the
+# CPU platform keeps it (and every --all child, which inherits the
+# environment) off any TPU on the host.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell against the production meshes, record memory/cost/collective
@@ -83,57 +85,23 @@ def _abstract_opt(params):
 def abstract_lut_params(cfg, ctx: Ctx, chunk_size: int = 1,
                         fsdp_tables: bool = False):
     """Shape/sharding stand-ins for a TableNet-converted parameter tree:
-    eval_shape through the conversion pass, tables sharded on their output
-    dim like the weights they replace.  Works for both per-projection
-    ``LUTLinear`` and pre-stacked ``LUTGroup`` leaves: either way the
-    ``tables`` leaf ends in ``(..., k, entries, p)`` with ``p`` last and
-    ``k`` third-from-last, which is all the sharding rules key on."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
+    eval_shape through the conversion pass, placed by
+    ``dist.sharding.converted_shardings`` (tables sharded on their output
+    dim like the weights they replace)."""
     from repro.core.convert import convert_params
+    from repro.dist.sharding import converted_shardings
 
     std = _abstract_state(cfg, ctx, jnp.bfloat16)
     shapes = jax.eval_shape(
         lambda p: convert_params(p, chunk_size=chunk_size, table_dtype=jnp.bfloat16)[0],
         std,
     )
-
-    def shard(path, leaf):
-        # dict levels carry DictKey (.key); LUTLinear/LUTGroup children
-        # carry GetAttrKey (.name)
-        name = getattr(path[-1], "key", None) or getattr(path[-1], "name", None)
-        name = name if name is not None else str(path[-1])
-        if name == "tables":
-            p_out = leaf.shape[-1]
-            n_model = ctx.shard.axis_size("model")
-            tp = "model" if n_model and p_out % n_model == 0 else None
-            axes = [None] * (leaf.ndim - 1) + [tp]
-            if fsdp_tables:  # shard the chunk dim over data (ZeRO-3 tables)
-                k = leaf.shape[-3]
-                if k % max(ctx.shard.axis_size("data"), 1) == 0:
-                    axes[-3] = "data"
-            spec = P(*axes)
-            return jax.ShapeDtypeStruct(
-                leaf.shape, leaf.dtype, sharding=NamedSharding(ctx.shard.mesh, spec)
-            )
-        return jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype,
-            sharding=NamedSharding(ctx.shard.mesh, P(*([None] * leaf.ndim))),
-        )
-
-    # reuse original shardings where paths coincide (embed, norms, biases...)
-    std_flat = dict(
-        (jax.tree_util.keystr(p), l)
-        for p, l in jax.tree_util.tree_flatten_with_path(std)[0]
+    shardings = converted_shardings(shapes, std, ctx.shard, fsdp_tables=fsdp_tables)
+    return jax.tree.map(
+        lambda leaf, s: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=s),
+        shapes,
+        shardings,
     )
-
-    def build(path, leaf):
-        key = jax.tree_util.keystr(path)
-        if key in std_flat and std_flat[key].shape == leaf.shape:
-            return std_flat[key]
-        return shard(path, leaf)
-
-    return jax.tree_util.tree_map_with_path(build, shapes)
 
 
 def lower_cell(
@@ -195,8 +163,6 @@ def lower_cell(
 def _raw_costs(compiled) -> "np.ndarray":
     """[flops, hbm_bytes, link_bytes] of one compiled per-device module."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     if not hbm:
@@ -282,7 +248,8 @@ def analyse(compiled, cfg, case, mesh_kind: str, corrected=None, probes=None) ->
             "alias_mib": getattr(ma, "alias_size_in_bytes", 0) / 2**20,
         }
     coll = H.collective_stats(compiled.as_text())
-    terms = H.roofline_terms(flops, hbm_bytes, link_bytes)
+    # the fake CPU devices stand in for v5e chips: roofline at v5e peaks
+    terms = H.roofline_terms(flops, hbm_bytes, link_bytes, device_kind=H.V5E)
     mflops = H.model_flops(cfg, case)
     useful = mflops / chips / flops if flops else 0.0
     return {

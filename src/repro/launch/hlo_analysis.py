@@ -10,17 +10,39 @@ ring-algorithm model over the op's replica-group size N:
   all-to-all       (N-1)/N * payload
   collective-permute  payload               (one hop per chip)
 
-Hardware model (TPU v5e-class, per the assignment): 197 bf16 TFLOP/s,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the published per-chip peaks of :data:`PEAKS`, looked up
+by the ``device_kind`` JAX reports; a kind missing from the table raises.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s / chip
-LINK_BW = 50e9  # B/s / link
+V5E = "TPU v5 lite"  # jax's device_kind for a TPU v5e chip
+
+# Published per-chip peaks keyed by ``device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM
+# at 819 GB/s, 1,600 Gbit/s inter-chip interconnect over 4 links.
+PEAKS = {
+    V5E: {
+        "bf16_flops": 197e12,  # FLOP/s
+        "int8_ops": 393e12,  # OP/s
+        "hbm_bw": 819e9,  # B/s
+        "hbm_bytes": 16e9,
+        "link_bw": 50e9,  # B/s per ICI link
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The :data:`PEAKS` row of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -116,10 +138,14 @@ def roofline_terms(
     flops_per_device: float,
     hbm_bytes_per_device: float,
     link_bytes_per_device: float,
+    device_kind: str = V5E,
 ) -> dict:
-    compute = flops_per_device / PEAK_FLOPS
-    memory = hbm_bytes_per_device / HBM_BW
-    collective = link_bytes_per_device / LINK_BW
+    """Seconds each resource needs at ``device_kind``'s bf16, HBM and ICI
+    link peaks, the dominant one, and the roofline fraction."""
+    pk = peaks(device_kind)
+    compute = flops_per_device / pk["bf16_flops"]
+    memory = hbm_bytes_per_device / pk["hbm_bw"]
+    collective = link_bytes_per_device / pk["link_bw"]
     terms = {"compute_s": compute, "memory_s": memory, "collective_s": collective}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
@@ -127,6 +153,7 @@ def roofline_terms(
     # roofline fraction: how much of the binding resource's time is the
     # compute we actually want (1.0 == perfectly compute-bound at peak)
     terms["roofline_fraction"] = compute / bound if bound > 0 else 0.0
+    terms["device_kind"] = device_kind
     return terms
 
 

@@ -8,13 +8,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int | None = None, model: int = 1):
@@ -23,5 +23,5 @@ def make_host_mesh(data: int | None = None, model: int = 1):
     if data is None:
         data = n // model
     assert data * model <= n, (data, model, n)
-    return make_mesh((data, model), ("data", "model"),
+    return jax.make_mesh((data, model), ("data", "model"),
                      axis_types=(AxisType.Auto, AxisType.Auto))

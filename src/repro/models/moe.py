@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core.convert import LUTGroup, LUTLinear
 from repro.core.lut import LUTPlan, pack_codes, plane_scales
@@ -389,7 +388,7 @@ def moe_ffn(p: dict, x: jax.Array, ctx: Ctx):
             psum_axes=tp,
             mean_axes=tuple(dp) + tuple(tp),
         )
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             fn,
             mesh=sh.mesh,
             in_specs=(tok_spec, _expert_specs(experts, tp)),

@@ -82,7 +82,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models.layers import Ctx, SampleCfg, sample_tokens
 from repro.models.model import model_forward
-from repro.models.params import abstract_params, init_params
+from repro.models.params import abstract_params, init_params, tree_map_specs
 from repro.serve._cache import CacheOverflowError, cache_specs, copy_pages
 from repro.serve._paging import PageAllocator, PagePoolExhausted, _prefix_key
 
@@ -122,6 +122,10 @@ def make_cache(
         cfg, batch, max_len, page_size=page_size, num_pages=num_pages
     )
     cache = init_params(specs, jax.random.PRNGKey(0), default_dtype=dtype)
+    if ctx.shard.mesh is not None:  # spread over the mesh like abstract_cache
+        cache = jax.device_put(
+            cache, tree_map_specs(ctx.shard.param_sharding, specs)
+        )
     if page_size is not None:
         max_pages = cache["pos"].shape[1] // page_size
         if page_table == "identity":
@@ -639,6 +643,11 @@ class BatchingEngine:
                         self.alloc.retire(s)
                 else:
                     self.slots[s] = req
+
+    def lower_decode(self):
+        """The engine's decode step lowered for its current params and cache
+        (``.compile()`` it to inspect the program each step runs)."""
+        return self._decode.lower(self.params, self.cache)
 
     def step(self) -> bool:
         """One decode step over all active slots; returns True if any active."""

@@ -13,13 +13,16 @@ def cross_entropy(
     """Returns (mean loss over valid tokens, valid-token count)."""
     Vp = logits.shape[-1]
     lf = logits.astype(jnp.float32)
+    col = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
     if Vp > vocab_size:  # mask Megatron-style vocab padding columns
-        col = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
         lf = jnp.where(col < vocab_size, lf, -1e9)
     valid = labels >= 0
     safe = jnp.where(valid, labels, 0)
     lse = jax.nn.logsumexp(lf, axis=-1)
-    picked = jnp.take_along_axis(lf, safe[..., None], axis=-1)[..., 0]
+    # the label's logit as a select-and-sum over the vocab axis (exact: one
+    # term is non-zero), not a gather: XLA's SPMD partitioner aborts on a
+    # gather over a sharded vocab inside the pod-manual shard_map
+    picked = jnp.sum(jnp.where(col == safe[..., None], lf, 0.0), axis=-1)
     nll = jnp.where(valid, lse - picked, 0.0)
     count = jnp.maximum(valid.sum(), 1)
     return nll.sum() / count, count

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 from repro.dist import checkpoint as ckpt
 from repro.dist.compression import compressed_psum
 from repro.models.layers import Ctx
@@ -135,9 +134,11 @@ def make_grad_fn(ctx: Ctx, tc: TrainConfig):
         def per_pod(params, batch, err):
             # mark params pod-VARYING: otherwise the autodiff transpose
             # inserts an implicit (uncompressed!) psum over "pod" for
-            # grads of replicated inputs — pvary keeps the partials local
+            # grads of replicated inputs — pcast keeps the partials local
             # so the only cross-pod traffic is the int8 payload below
-            params = jax.tree.map(lambda a: pvary(a, "pod"), params)
+            params = jax.tree.map(
+                lambda a: jax.lax.pcast(a, "pod", to="varying"), params
+            )
             g, loss, aux = grads_of(params, batch)
             # error-feedback state has an explicit leading pod dim
             g, new_err = compressed_psum(
@@ -149,12 +150,17 @@ def make_grad_fn(ctx: Ctx, tc: TrainConfig):
         b_specs = jax.tree.map(lambda _: P("pod"), batch)
         n_specs = jax.tree.map(lambda _: P(), params)
         e_specs = jax.tree.map(lambda _: P("pod"), err)
-        return shard_map(
-            per_pod,
-            mesh=mesh,
-            in_specs=(n_specs, b_specs, e_specs),
-            out_specs=(n_specs, P(), P(), e_specs),
-            axis_names={"pod"},
+        # jitted: JAX has no eager path for a partly manual shard_map with
+        # check_vma off (a no-op when the caller already traces)
+        return jax.jit(
+            jax.shard_map(
+                per_pod,
+                mesh=mesh,
+                in_specs=(n_specs, b_specs, e_specs),
+                out_specs=(n_specs, P(), P(), e_specs),
+                axis_names={"pod"},
+                check_vma=False,
+            )
         )(params, batch, err)
 
     return compressed

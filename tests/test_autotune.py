@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs.base import get_config
 from repro.core.planner import ModelPlan, plan_model
+from repro.kernels.common import MAX_SELECT_ENTRIES
 from repro.kernels.lut_affine import autotune
 from repro.kernels.lut_affine.autotune import (
     TunePoint,
@@ -35,6 +36,7 @@ def mplan():
         modes=("bitplane", "bitplane_shift"),
         radices=(1, 2, 4),
         table_formats=(None, "i8"),
+        max_entries=MAX_SELECT_ENTRIES,
     )
 
 
@@ -50,10 +52,12 @@ def test_candidates_are_legal():
     for bb, bp, bk in cands:
         assert bb % 8 == 0
         assert bp % 128 == 0
-        assert bk & (bk - 1) == 0  # power of two
-        assert bk <= pt.k
-        # live table tile respects the kernel's VMEM budget, G-aware
-        assert pt.G * bk * pt.entries * bp * pt.table_bytes <= autotune._VMEM_BUDGET
+        # the chunk tile is the codes' lane axis: the whole axis when short
+        assert bk == pt.k
+        # double-buffered blocks respect the kernel's VMEM budget
+        assert autotune.vmem_bytes(pt, (bb, bp, bk)) <= autotune.VMEM_BUDGET
+    long = dataclasses.replace(pt, k=1000)
+    assert {bk for _, _, bk in candidate_blocks(long)} <= {128, 256, 512, 1024}
 
 
 def test_candidates_exclude_vmem_busting_tiles():

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
 from repro.dist import checkpoint as ckpt
 from repro.dist.compression import compressed_psum
 from repro.dist.sharding import (
@@ -39,7 +38,7 @@ def test_shardctx_no_mesh_is_inert():
 
 def test_shardctx_constructor_forms():
     # the three forms the consumers use: (), (mesh), (mesh, rules)
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
     inner = rules_without_axis(DEFAULT_RULES, "pod")
     assert ShardCtx().mesh is None
     assert ShardCtx(mesh).rules == DEFAULT_RULES
@@ -136,16 +135,16 @@ def test_restore_rejects_mismatched_trees(tmp_path):
 def test_compressed_psum_single_device_round_trip():
     """On a 1-way axis the mean is the identity up to quantisation, and the
     residual is exactly what quantisation dropped."""
-    mesh = make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",))
     g = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
     err0 = jnp.zeros_like(g)
 
     def body(g, e):
         return compressed_psum({"w": g}, {"w": e}, "pod")
 
-    out, new_err = shard_map(
+    out, new_err = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        axis_names={"pod"},
+        axis_names={"pod"}, check_vma=False,
     )(g, err0)
     scale = float(jnp.abs(g).max()) / 127.0
     np.testing.assert_allclose(
@@ -155,9 +154,9 @@ def test_compressed_psum_single_device_round_trip():
     assert float(jnp.abs(out["w"] - g).max()) <= scale * 0.51
     assert float(jnp.abs(new_err["w"]).max()) <= scale * 0.51
     # error feedback: feeding the residual back cancels it
-    out2, err2 = shard_map(
+    out2, err2 = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-        axis_names={"pod"},
+        axis_names={"pod"}, check_vma=False,
     )(g, new_err["w"])
     np.testing.assert_allclose(
         np.asarray(out2["w"]) + np.asarray(err2["w"]),

@@ -36,9 +36,9 @@ pytestmark = pytest.mark.slow  # interpret-mode Pallas sweeps: ~45s on CPU
     [
         (1, 1, 1, 2, 1),  # degenerate minimum
         (4, 3, 7, 8, 10),  # ragged everything
-        (16, 11, 32, 64, 96),  # fp16-style planes
+        (16, 11, 32, 4, 96),  # fp16-style planes (bitplane_shift tables)
         (3, 4, 130, 16, 130),  # k and p beyond one block
-        (130, 2, 5, 256, 257),  # batch beyond one block, odd p
+        (130, 2, 5, 8, 257),  # batch beyond one block, odd p
     ],
 )
 def test_lut_affine_matches_ref(B, n, k, E, p, dtype):
@@ -94,9 +94,9 @@ def test_lut_affine_end_to_end_exact_vs_core():
     [
         (1, 1, 1, 1, 2, 1),  # degenerate minimum
         (3, 4, 3, 7, 8, 10),  # QKV-style group, ragged everything
-        (2, 16, 11, 32, 64, 96),  # gate/up-style group, fp16 planes
+        (2, 16, 11, 32, 4, 96),  # gate/up-style group, fp16 planes
         (4, 3, 4, 130, 16, 130),  # k and p beyond one block
-        (2, 130, 2, 5, 256, 257),  # batch beyond one block, odd p
+        (2, 130, 2, 5, 8, 257),  # batch beyond one block, odd p
     ],
 )
 def test_lut_affine_grouped_matches_ref(G, B, n, k, E, p, dtype):
@@ -142,8 +142,8 @@ def test_lut_affine_grouped_leading_dims_and_bias():
     [
         (1, 1, 1, 1, 1, 2, 1, (1,)),  # degenerate minimum
         (4, 2, 11, 3, 7, 8, 10, (3, 0, 6, 2)),  # gate/up stack, empty group
-        (8, 1, 16, 11, 32, 64, 96, (2,) * 8),  # w_down stack, fp16 planes
-        (3, 2, 130, 2, 5, 64, 129, (50, 0, 80)),  # T and p beyond one block
+        (8, 1, 16, 11, 32, 4, 96, (2,) * 8),  # w_down stack, fp16 planes
+        (3, 2, 130, 2, 5, 16, 129, (50, 0, 80)),  # T and p beyond one block
         (2, 2, 6, 4, 130, 16, 130, (1, 5)),  # k beyond one block, skewed
     ],
 )
@@ -190,26 +190,36 @@ def test_lut_affine_experts_equals_segmented_per_expert_dispatch():
 
 
 def test_pick_blocks_respects_vmem_budget_for_groups():
-    """Regression: block selection must account for the group dim G — the
-    grouped grid keeps G projections' table tiles live, so the VMEM bound
-    is G * block_k * E * block_p * 4 bytes, not the per-projection bound."""
-    from repro.kernels.lut_affine.ops import _VMEM_BUDGET, _pick_blocks
+    """The default tiling obeys the (8, 128) tiling and the VMEM budget at
+    every group fan-out G.  The grouped grid walks G, holding one member's
+    double-buffered table tile at a time, so G must not change the pick."""
+    from repro.kernels.lut_affine.autotune import (
+        VMEM_BUDGET,
+        TunePoint,
+        block_k_options,
+        pick_blocks,
+        vmem_bytes,
+    )
 
-    shapes = [
-        (7, 128, 64),
-        (64, 2**12, 512),
-        (32, 2**14, 96),
-        (128, 2**7, 4096),
-        (64, 2**12, 300),  # ragged p: shrink must stay on 128-multiples
+    shapes = [  # (k, E, p)
+        (7, 2, 64),
+        (1024, 16, 4096),  # granite_8b attention, 4-element chunks
+        (3584, 16, 4096),  # granite_8b w_down
+        (4096, 4, 14336),  # bitplane_shift, one element per chunk
+        (352, 16, 300),  # ragged k and p: padded to the tiling
     ]
-    for G in (1, 2, 3, 8):
+    for table_bytes in (1, 2, 4):
         for k, E, p in shapes:
-            _, block_p, block_k = _pick_blocks(8, k, E, p, 11, G=G)
-            assert block_p % 128 == 0, (G, k, E, p, block_p)  # Mosaic lane dim
-            if G * E * 128 * 4 > _VMEM_BUDGET:
-                continue  # even a minimal tile cannot fit; nothing to assert
-            live = G * block_k * E * block_p * 4
-            assert live <= _VMEM_BUDGET, (G, k, E, p, block_p, block_k, live)
+            picks = set()
+            for G in (1, 2, 3, 8):
+                pt = TunePoint(B=8, k=k, entries=E, p=p, n=11, G=G,
+                               table_bytes=table_bytes)
+                bb, bp, bk = pick_blocks(pt)
+                assert bb % 8 == 0 and bp % 128 == 0, (pt, bb, bp)
+                assert bk in block_k_options(k), (pt, bk)
+                assert vmem_bytes(pt, (bb, bp, bk)) <= VMEM_BUDGET, pt
+                picks.add((bb, bp, bk))
+            assert len(picks) == 1, (k, E, p, picks)
 
 
 # ---------------------------------------------------------------------------

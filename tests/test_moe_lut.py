@@ -21,6 +21,7 @@ from repro.audit import multiplier_free_violations
 from repro.configs.base import get_config
 from repro.core.convert import LUTGroup, LUTLinear, convert_params
 from repro.core.planner import plan_model
+from repro.kernels.common import MAX_SELECT_ENTRIES
 from repro.models.layers import Ctx, ExecCfg
 from repro.models.model import model_forward, model_specs
 from repro.models.moe import moe_ffn, moe_specs
@@ -66,7 +67,21 @@ def test_moe_ffn_lut_experts_match_dense(chunk, use_pallas):
     cfg, p, x = _ffn_setup()
     ctx = Ctx(cfg, ex=ExecCfg(remat="none", use_pallas=use_pallas))
     want, aux_want = moe_ffn(p, x, Ctx(cfg, ex=ExecCfg(remat="none")))
-    lut, rep = convert_params(p, chunk_size=chunk, convert_experts=True)
+    if use_pallas:
+        # the kernels serve at most MAX_SELECT_ENTRIES-entry tables; the fp16
+        # plans under that cap are bitplane_shift, here with ``chunk``
+        # mantissa bits per plane
+        mplan = plan_model(
+            p,
+            float("inf"),
+            modes=("bitplane_shift",),
+            radices=(chunk,),
+            convert_experts=True,
+            max_entries=MAX_SELECT_ENTRIES,
+        )
+        lut, rep = convert_params(p, plan=mplan, convert_experts=True)
+    else:
+        lut, rep = convert_params(p, chunk_size=chunk, convert_experts=True)
     assert isinstance(lut["w_gate+w_up"], LUTGroup)  # pre-stacked pair
     assert isinstance(lut["w_down"], LUTLinear)
     got, aux_got = moe_ffn(lut, x, ctx)

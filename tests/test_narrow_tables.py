@@ -83,7 +83,7 @@ def test_table_scale_trailing_shapes():
 _GRID = [
     (1, 1, 1, 2, 1),  # degenerate minimum
     (4, 3, 7, 8, 10),  # ragged everything
-    (16, 3, 32, 32, 96),  # bitplane_shift-style planes
+    (16, 3, 32, 16, 96),  # bitplane_shift-style planes
     (3, 2, 130, 16, 130),  # k and p beyond one block
 ]
 
@@ -137,14 +137,14 @@ def _shift_codes(key, shape, index_bits):
     return field | (exp << index_bits)
 
 
-@pytest.mark.parametrize("B,n,k,E,p", [(4, 3, 7, 32, 10), (9, 3, 130, 32, 130)])
+@pytest.mark.parametrize("B,n,k,E,p", [(4, 4, 7, 16, 10), (9, 4, 130, 16, 130)])
 def test_lut_affine_shift_bits_matches_ref(B, n, k, E, p):
-    index_bits = 5
+    index_bits = 4
     assert E == 2**index_bits
     kc, kt = jax.random.split(jax.random.PRNGKey(B + k))
     codes = _shift_codes(kc, (B, n, k), index_bits)
-    tables = jax.random.randint(kt, (k, E, p), -15, 16, jnp.int32).astype(jnp.int8)
-    scales = 2.0 ** (4.0 * jnp.arange(n, dtype=jnp.float32))  # radix-4 planes
+    tables = jax.random.randint(kt, (k, E, p), -7, 8, jnp.int32).astype(jnp.int8)
+    scales = 2.0 ** (3.0 * jnp.arange(n, dtype=jnp.float32))  # radix-3 planes
     got = lut_affine(codes, tables, scales, shift_bits=index_bits, interpret=True)
     want = lut_affine_ref(codes, tables, scales, shift_bits=index_bits)
     rel = 1e-5
@@ -153,12 +153,12 @@ def test_lut_affine_shift_bits_matches_ref(B, n, k, E, p):
 
 
 def test_grouped_shift_bits_matches_ref():
-    index_bits, G, B, n, k, p = 5, 2, 4, 3, 16, 40
+    index_bits, G, B, n, k, p = 4, 2, 4, 4, 16, 40
     E = 2**index_bits
     kc, kt = jax.random.split(jax.random.PRNGKey(3))
     codes = _shift_codes(kc, (B, n, k), index_bits)
-    tables = jax.random.randint(kt, (G, k, E, p), -15, 16, jnp.int32).astype(jnp.int8)
-    scales = 2.0 ** (4.0 * jnp.arange(n, dtype=jnp.float32))
+    tables = jax.random.randint(kt, (G, k, E, p), -7, 8, jnp.int32).astype(jnp.int8)
+    scales = 2.0 ** (3.0 * jnp.arange(n, dtype=jnp.float32))
     got = lut_affine_grouped(
         codes, tables, scales, shift_bits=index_bits, interpret=True
     )

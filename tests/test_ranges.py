@@ -190,13 +190,11 @@ def test_tl1_cert_exact_path_is_float_and_errorless():
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=15)
-@given(
-    q=st.sampled_from([5, 24, 64]),
-    act_bits=st.sampled_from([2, 4, 8]),
-    seed=st.integers(min_value=0, max_value=2**31 - 1),
-)
-def test_tl1_empirical_acc_within_static_bound(q, act_bits, seed):
+_TL1_QS, _TL1_BITS = (5, 24, 64), (2, 4, 8)
+_RADICES, _FORMATS = (1, 2, 4), ("i8", "i16")
+
+
+def _tl1_case(q, act_bits, seed):
     p = 8
     plan = TL1Plan(q, p, act_bits=act_bits)
     cert = layer_range_cert(plan)
@@ -212,13 +210,7 @@ def test_tl1_empirical_acc_within_static_bound(q, act_bits, seed):
     assert float(jnp.max(jnp.abs(lut))) <= cert.entry_max
 
 
-@settings(max_examples=10)
-@given(
-    radix=st.sampled_from([1, 2, 4]),
-    table_format=st.sampled_from(["i8", "i16"]),
-    seed=st.integers(min_value=0, max_value=2**31 - 1),
-)
-def test_weight_empirical_acc_within_static_bound(radix, table_format, seed):
+def _weight_case(radix, table_format, seed):
     q, p = 24, 8
     plan = LUTPlan(
         q,
@@ -236,6 +228,41 @@ def test_weight_empirical_acc_within_static_bound(radix, table_format, seed):
     dequant = narrow.astype(jnp.float32) * scale
     acc = apply_luts(dequant, pack_codes(x, plan), plan)
     assert float(jnp.max(jnp.abs(acc))) <= cert.max_abs_acc
+
+
+@pytest.fixture(scope="module")
+def compiled_cases():
+    """Run every shape the property tests draw once, so JAX compiles its
+    eager ops here and not inside an example hypothesis times against its
+    deadline (the first call of a new shape takes seconds, later ones ms)."""
+    for q in _TL1_QS:
+        for act_bits in _TL1_BITS:
+            _tl1_case(q, act_bits, 0)
+    for radix in _RADICES:
+        for table_format in _FORMATS:
+            _weight_case(radix, table_format, 0)
+
+
+@pytest.mark.usefixtures("compiled_cases")
+@settings(max_examples=15)
+@given(
+    q=st.sampled_from(_TL1_QS),
+    act_bits=st.sampled_from(_TL1_BITS),
+    seed=st.integers(min_value=0, max_value=2**31 - 1),
+)
+def test_tl1_empirical_acc_within_static_bound(q, act_bits, seed):
+    _tl1_case(q, act_bits, seed)
+
+
+@pytest.mark.usefixtures("compiled_cases")
+@settings(max_examples=10)
+@given(
+    radix=st.sampled_from(_RADICES),
+    table_format=st.sampled_from(_FORMATS),
+    seed=st.integers(min_value=0, max_value=2**31 - 1),
+)
+def test_weight_empirical_acc_within_static_bound(radix, table_format, seed):
+    _weight_case(radix, table_format, seed)
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Hillclimb profiler: per-layer cost breakdown of one cell via depth probes.
 
   PYTHONPATH=src python tools/perf_probe.py granite_8b train_4k single \
@@ -8,11 +5,19 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 Prints the per-LAYER collective ops (d2 - d1 diff), and per-layer
 flops/bytes — the "profile" the optimization loop reads.
 """
+# ruff: noqa: E402 -- the backend is configured before any jax import
 import argparse
 import json
+import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+from repro.launch.backend import BackendConfig
+
+# 512 fake CPU devices, pinned to the CPU platform so the probe never opens
+# a TPU on the host
+BackendConfig(platform="cpu", host_device_count=512).apply()
 
 from repro.launch import hlo_analysis as H
 from repro.launch.dryrun import _raw_costs, lower_cell
