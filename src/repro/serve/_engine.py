@@ -65,6 +65,13 @@ dropped.  In paged mode, pool exhaustion defers admission while any slot
 is active (retires will free pages) and raises ``CacheOverflowError`` when
 nothing can ever free one.
 
+Tracing: the engine marks its host work with ``jax.profiler.TraceAnnotation``
+spans, ``serve.step`` around each step and, inside it, ``serve.admit`` (per
+admission round; ``serve.plan``, ``serve.prefill``), ``serve.pages``,
+``serve.decode`` and ``serve.readback``.  They record only while a profiler
+trace is active.  ``engine.prefill_rows`` counts the token rows the
+prefills computed, padding included, beside ``prefill_tokens``.
+
 ``decode_step`` is what the decode_32k / long_500k dry-run cells lower: one
 new token against a seq_len-deep cache, caches seq-sharded over the model
 axis (DESIGN.md §4).
@@ -78,6 +85,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import Ctx, SampleCfg, sample_tokens
@@ -500,6 +508,7 @@ class BatchingEngine:
         )
         self.readbacks = 0  # host syncs: 1/decode step + 1/admission prefill
         self.prefill_tokens = 0  # tokens actually prefilled (tails only)
+        self.prefill_rows = 0  # token rows prefills computed, padding included
         self._slot_len = [0] * num_slots  # host mirror of per-slot index
 
     def submit(self, req: Request):
@@ -525,13 +534,15 @@ class BatchingEngine:
 
     def _check(self, packed) -> np.ndarray:
         """The ONE host readback per step; backstop overflow check."""
-        arr = np.asarray(packed)
-        self.readbacks += 1
-        if arr[:, 2].any():
-            raise CacheOverflowError(
-                f"cache overflow flagged for slots {arr[:, 2].nonzero()[0].tolist()}"
-            )
-        return arr
+        with TraceAnnotation("serve.readback"):
+            arr = np.asarray(packed)
+            self.readbacks += 1
+            if arr[:, 2].any():
+                raise CacheOverflowError(
+                    "cache overflow flagged for slots "
+                    f"{arr[:, 2].nonzero()[0].tolist()}"
+                )
+            return arr
 
     def _plan_batch(self, free: list[int], limit: int):
         """Pop up to ``limit`` admittable requests, assigning slots (and,
@@ -577,8 +588,16 @@ class BatchingEngine:
 
     def _admit(self):
         while self.queue and any(s is None for s in self.slots):
-            free = [i for i, s in enumerate(self.slots) if s is None]
-            limit = 1 if self.admit_mode == "per-slot" else len(free)
+            with TraceAnnotation("serve.admit") as span:
+                if not self._admit_round(span):
+                    return
+
+    def _admit_round(self, span) -> bool:
+        """Plan one round, prefill every placed request in one call and read
+        the first tokens back; False when nothing could be placed."""
+        free = [i for i, s in enumerate(self.slots) if s is None]
+        limit = 1 if self.admit_mode == "per-slot" else len(free)
+        with TraceAnnotation("serve.plan"):
             placed = self._plan_batch(free, limit)
             if not placed:
                 if (
@@ -591,7 +610,7 @@ class BatchingEngine:
                         f"request {req.uid}: page pool exhausted with no "
                         "active slots to retire; raise num_pages"
                     )
-                return
+                return False
             B = self.num_slots
             tails = [
                 np.asarray(r.prompt, np.int32)[(p.start if p else 0):]
@@ -615,34 +634,44 @@ class BatchingEngine:
                 if plan is not None:
                     starts[s] = plan.start
                     copy_src[s], copy_dst[s] = plan.copy_src, plan.copy_dst
-            if self.alloc is not None:
-                self.cache["page_table"] = jnp.asarray(self.alloc.table)
+        n_tokens = int(sum(len(t) for t in tails))
+        if TraceAnnotation.is_enabled():
+            span.set_metadata(
+                rows=B * S, tokens=n_tokens,
+                uids=" ".join(str(r.uid) for r, _, _ in placed),
+            )
+        if self.alloc is not None:
+            self.cache["page_table"] = jnp.asarray(self.alloc.table)
+            with TraceAnnotation("serve.prefill"):
                 self.cache, packed = self._prefill(
                     self.params, self.cache, tokens, lens, admit, uids,
                     max_news, self._base_key, starts, copy_src, copy_dst,
                 )
-                for (req, s, plan), tail in zip(placed, tails):
-                    # the prefill writing these pages has been issued: safe
-                    # to register them for future admissions to map
-                    self.alloc.register(s, np.asarray(req.prompt, np.int32))
-                    self._slot_len[s] = int(plan.start) + len(tail)
-            else:
+            for (req, s, plan), tail in zip(placed, tails):
+                # the prefill writing these pages has been issued: safe
+                # to register them for future admissions to map
+                self.alloc.register(s, np.asarray(req.prompt, np.int32))
+                self._slot_len[s] = int(plan.start) + len(tail)
+        else:
+            with TraceAnnotation("serve.prefill"):
                 self.cache, packed = self._prefill(
                     self.params, self.cache, tokens, lens, admit, uids,
                     max_news, self._base_key,
                 )
-                for (req, s, _), tail in zip(placed, tails):
-                    self._slot_len[s] = len(tail)
-            self.prefill_tokens += int(sum(len(t) for t in tails))
-            arr = self._check(packed)
-            for (req, s, _), _tail in zip(placed, tails):
-                req.generated.append(int(arr[s, 0]))
-                if arr[s, 1]:  # EOS at prefill or max_new == 1: free the
-                    req.done = True  # slot now; keep admitting into it
-                    if self.alloc is not None:
-                        self.alloc.retire(s)
-                else:
-                    self.slots[s] = req
+            for (req, s, _), tail in zip(placed, tails):
+                self._slot_len[s] = len(tail)
+        self.prefill_tokens += n_tokens
+        self.prefill_rows += B * S
+        arr = self._check(packed)
+        for req, s, _ in placed:
+            req.generated.append(int(arr[s, 0]))
+            if arr[s, 1]:  # EOS at prefill or max_new == 1: free the
+                req.done = True  # slot now; keep admitting into it
+                if self.alloc is not None:
+                    self.alloc.retire(s)
+            else:
+                self.slots[s] = req
+        return True
 
     def lower_decode(self):
         """The engine's decode step lowered for its current params and cache
@@ -651,34 +680,42 @@ class BatchingEngine:
 
     def step(self) -> bool:
         """One decode step over all active slots; returns True if any active."""
-        self._admit()
-        if all(r is None for r in self.slots):
-            return False
-        if self.alloc is not None:
-            dirty = False
+        with TraceAnnotation("serve.step"):
+            self._admit()
+            if all(r is None for r in self.slots):
+                return False
+            if self.alloc is not None:
+                with TraceAnnotation("serve.pages"):
+                    self._map_pages()
+            with TraceAnnotation("serve.decode"):
+                self.cache, packed = self._decode(self.params, self.cache)
+            arr = self._check(packed)
             for s, req in enumerate(self.slots):
-                if req is not None:
-                    try:
-                        # the decode below writes this slot's KV at its
-                        # current length: map that page before tracing
-                        dirty |= self.alloc.ensure_page(s, self._slot_len[s])
-                    except PagePoolExhausted as e:
-                        raise CacheOverflowError(str(e)) from None
-            if dirty:
-                self.cache["page_table"] = jnp.asarray(self.alloc.table)
-        self.cache, packed = self._decode(self.params, self.cache)
-        arr = self._check(packed)
+                if req is None:
+                    continue
+                self._slot_len[s] += 1
+                req.generated.append(int(arr[s, 0]))
+                if arr[s, 1]:
+                    req.done = True
+                    self.slots[s] = None
+                    if self.alloc is not None:
+                        self.alloc.retire(s)
+            return True
+
+    def _map_pages(self):
+        """Map the page each active slot's next decode write lands in, and
+        upload the table if that changed it."""
+        dirty = False
         for s, req in enumerate(self.slots):
-            if req is None:
-                continue
-            self._slot_len[s] += 1
-            req.generated.append(int(arr[s, 0]))
-            if arr[s, 1]:
-                req.done = True
-                self.slots[s] = None
-                if self.alloc is not None:
-                    self.alloc.retire(s)
-        return True
+            if req is not None:
+                try:
+                    # the decode writes this slot's KV at its current
+                    # length: map that page before tracing
+                    dirty |= self.alloc.ensure_page(s, self._slot_len[s])
+                except PagePoolExhausted as e:
+                    raise CacheOverflowError(str(e)) from None
+        if dirty:
+            self.cache["page_table"] = jnp.asarray(self.alloc.table)
 
     def run(self) -> list[Request]:
         all_reqs = list(self.queue)
