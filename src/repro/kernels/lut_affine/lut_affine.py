@@ -18,9 +18,30 @@ and a chunk is a lane column of a 128-lane window, brought to lane 0 by a
 rotate.  Tile shapes obey the (8, 128) tiling: the batch tile is a multiple
 of 8, the output tile a multiple of 128, and the chunk tile a multiple of
 128 or the whole chunk axis (its codes then padded to one 128-lane window,
-``common.code_lanes``).  The plane and chunk loops are ``fori_loop``s.
+``common.code_lanes``).
+
+A grid step walks its chunks and planes in one of two orders, chosen at
+trace time from ``(block_b, block_p, planes)`` (``loop_order``):
+
+* chunk-outer, while the per-plane partials stay small (every decode
+  tile, and batch tiles up to 32 rows at 8 planes and 512 lanes).  Once
+  per chunk the table tile is loaded, widened to f32 and each row
+  broadcast to an (8, pb) block.  Then, per 8-row group and plane, only
+  the code column's rotate, broadcast and bit tests, the select tree and
+  one add into that plane's f32 partial (a VMEM scratch) remain.  The
+  planes of a chunk are independent of each other, so an iteration of
+  the chunk loop holds one chain per plane where the plane-outer order
+  has a single short dependent chain.
+* plane-outer, for wider batch tiles: per (plane, chunk) the whole
+  ``(bb, pb)`` block is gathered from the tile (``common.select_entries``)
+  into the plane's partial, a loop carry, so the table-side work is done
+  once per plane.
+
+Both keep one f32 partial per plane, add the chunks to it in chunk order
+and combine the partials as ``acc + scales[j] * plane_j`` in plane order,
+so they give the same bits.  The chunk loops are ``fori_loop``s.
 All accumulation is fp32 regardless of the table dtype — narrow
-(int8/int16) tables are widened per gathered row, their dequant scale folded
+(int8/int16) tables are widened to f32 in the kernel, their dequant scale folded
 into ``scales`` by the caller — matching the paper's full-precision-output
 claim.
 
@@ -31,6 +52,7 @@ the element's fp16 exponent, applied to the gathered row as
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -50,29 +72,95 @@ from repro.kernels.common import (
 _SCALES = pl.BlockSpec(memory_space=pltpu.SMEM)  # (n,) plane scales, whole
 
 
-def _gather_rows(table, code, shift_bits: int):
-    """(E, pb) table tile + (bb, 1) codes -> (bb, pb) f32 rows, sigma-scaled
-    when the codes carry an exponent in their high bits (bitplane_shift)."""
+def _table_rows(table):
+    """(E, pb) table tile -> its E rows, each (1, pb) f32."""
     rows = table.astype(jnp.float32)
-    E = rows.shape[0]
+    return [rows[e : e + 1] for e in range(rows.shape[0])]
+
+
+def _gather(rows, code, shift_bits: int):
+    """A chunk's E f32 table rows + (bb, 1) codes -> (bb, pb) gathered rows,
+    sigma-scaled when the codes carry an exponent in their high bits
+    (bitplane_shift)."""
+    E = len(rows)
     idx = code & (E - 1) if shift_bits else code
-    out = select_entries([rows[e : e + 1] for e in range(E)], idx)
+    out = select_entries(rows, idx)
     if shift_bits:
         sig = jnp.exp2(jnp.maximum(code >> shift_bits, 1).astype(jnp.float32) - 25.0)
         out = out * sig
     return out
 
 
-def _accumulate(codes_ref, table_at, scales_ref, shape, *, block_k, planes, shift_bits):
-    """``sum_j scales[j] * sum_c table_at(c)[codes[j, :, c]]`` over one
-    chunk tile, accumulated in fp32 plane by plane, chunk by chunk.
+# Rows of one f32 vreg: the chunk-outer order gathers 8 batch rows at a time.
+SUBLANES = 8
 
-    codes_ref : (n, bb, lanes) int32 VMEM — plane-major: a plane is a
-                leading-dim load, a chunk a lane column, read from one
-                128-lane window at a time (``common.code_lanes``)
-    table_at  : c -> (E, pb) table tile of chunk ``c``
-    scales_ref: (n,) f32 SMEM
-    """
+# Most f32 vregs of per-plane partials (planes x block_b x block_p / 1024)
+# for which a grid step walks chunks outside planes.  Measured on a v5e at
+# granite_8b's gate/up shape (8 planes, 512-lane output tiles), ms a call,
+# plane-outer against chunk-outer: 8 rows (32 vregs) 82.0 / 15.2; 32 rows
+# (128 vregs) 92.2 / 67.0; 64 rows (256 vregs) 118.5 / 130.2.
+CHUNK_OUTER_MAX_PARTIALS = 128
+
+# Kernels traced per loop order (``"chunk_outer"``, ``"plane_outer"``):
+# incremented when ``_accumulate`` is traced, so once per compiled kernel.
+ORDERS_COMPILED: collections.Counter = collections.Counter()
+
+
+def loop_order(block_b: int, block_p: int, planes: int) -> str:
+    """The loop order a ``(block_b, block_p)`` grid step over ``planes``
+    bitplanes compiles to (see ``CHUNK_OUTER_MAX_PARTIALS``)."""
+    partials = planes * block_b * block_p // (SUBLANES * LANES)
+    return "chunk_outer" if partials <= CHUNK_OUTER_MAX_PARTIALS else "plane_outer"
+
+
+def _chunk_outer(
+    codes_ref, table_at, scales_ref, shape, *, block_k, planes, shift_bits
+):
+    """Chunks outside, planes inside: each chunk's table tile is loaded,
+    widened and its rows broadcast to (8, pb) once, then every 8-row group
+    and every plane gathers from them into its own f32 partial, kept in a
+    VMEM scratch of ``(planes, bb, pb)``."""
+    bb, pb = shape
+    window = lane_window(block_k)
+
+    def body(parts_ref):
+        parts_ref[...] = jnp.zeros(parts_ref.shape, jnp.float32)
+
+        def window_body(w, carry):
+            c0 = window_start(w, block_k)
+
+            def chunk_body(c, carry):
+                rows = [
+                    jnp.broadcast_to(row, (SUBLANES, pb))
+                    for row in _table_rows(table_at(c0 + c))
+                ]
+
+                def group_body(g, carry):
+                    r = pl.ds(pl.multiple_of(g * SUBLANES, SUBLANES), SUBLANES)
+                    for j in range(planes):
+                        col = lane_column(codes_ref[j, r, pl.ds(c0, LANES)], c)
+                        gathered = _gather(rows, col, shift_bits)
+                        parts_ref[j, r, :] = parts_ref[j, r, :] + gathered
+                    return carry
+
+                return jax.lax.fori_loop(0, bb // SUBLANES, group_body, carry)
+
+            return jax.lax.fori_loop(0, window, chunk_body, carry)
+
+        jax.lax.fori_loop(0, block_k // window, window_body, 0)
+        acc = jnp.zeros(shape, jnp.float32)
+        for j in range(planes):
+            acc = acc + scales_ref[j] * parts_ref[j]
+        return acc
+
+    return pl.run_scoped(body, pltpu.VMEM((planes, bb, pb), jnp.float32))
+
+
+def _plane_outer(
+    codes_ref, table_at, scales_ref, shape, *, block_k, planes, shift_bits
+):
+    """Planes outside, chunks inside: each (plane, chunk) widens the table
+    tile and gathers its full ``(bb, pb)`` block from it."""
     window = lane_window(block_k)
 
     def plane_body(j, acc):
@@ -82,7 +170,8 @@ def _accumulate(codes_ref, table_at, scales_ref, shape, *, block_k, planes, shif
 
             def chunk_body(c, plane):
                 col = lane_column(codes, c)
-                return plane + _gather_rows(table_at(c0 + c), col, shift_bits)
+                rows = _table_rows(table_at(c0 + c))
+                return plane + _gather(rows, col, shift_bits)
 
             return jax.lax.fori_loop(0, window, chunk_body, plane)
 
@@ -92,6 +181,41 @@ def _accumulate(codes_ref, table_at, scales_ref, shape, *, block_k, planes, shif
         return acc + scales_ref[j] * plane
 
     return jax.lax.fori_loop(0, planes, plane_body, jnp.zeros(shape, jnp.float32))
+
+
+def _accumulate(
+    codes_ref, table_at, scales_ref, shape, *, block_k, planes, shift_bits
+):
+    """``sum_j scales[j] * sum_c table_at(c)[codes[j, :, c]]`` over one
+    chunk tile, in the loop order ``loop_order`` picks for the tile.
+
+    codes_ref : (n, bb, lanes) int32 VMEM — plane-major: a plane is a
+                leading-dim load, a chunk a lane column, read from one
+                128-lane window at a time (``common.code_lanes``)
+    table_at  : c -> (E, pb) table tile of chunk ``c``
+    scales_ref: (n,) f32 SMEM
+
+    Chunk-outer (``_chunk_outer``) loads, widens and broadcasts each
+    chunk's table tile once for all planes and 8-row groups; per plane and
+    group only the code column's rotate and bit tests, the select tree and
+    the add remain.  Plane-outer (``_plane_outer``) prepares the tile again
+    for every plane and gathers the whole ``(bb, pb)`` block at once.  Both
+    sum each plane's chunks into its own f32 partial in chunk order and
+    combine the partials as ``acc + scales[j] * plane_j`` in plane order,
+    so every output bit is the same whichever runs.
+    """
+    order = loop_order(*shape, planes)
+    ORDERS_COMPILED[order] += 1
+    fn = _chunk_outer if order == "chunk_outer" else _plane_outer
+    return fn(
+        codes_ref,
+        table_at,
+        scales_ref,
+        shape,
+        block_k=block_k,
+        planes=planes,
+        shift_bits=shift_bits,
+    )
 
 
 def _kernel(codes_ref, tables_ref, scales_ref, out_ref, **kw):

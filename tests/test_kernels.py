@@ -25,12 +25,51 @@ from repro.kernels.lut_affine.ref import (
 pytestmark = pytest.mark.slow  # interpret-mode Pallas sweeps: ~45s on CPU
 
 
+def _tables(key, shape, dtype):
+    """Random tables; int8 ones hold whole numbers, as converted tables do."""
+    if dtype == jnp.int8:
+        whole = jax.random.randint(key, shape, -128, 128, jnp.int32)
+        return whole.astype(jnp.int8)
+    return jax.random.normal(key, shape, dtype=jnp.float32).astype(dtype)
+
+
+def _scales(n, dtype):
+    """Plane scales.  With int8 tables they are the fixed-point planes'
+    powers of two, signed MSB negative, so every sum is exact in f32 in
+    any order and the kernel must equal the oracle bit for bit."""
+    if dtype == jnp.int8:
+        fmt = FixedPointFormat(n, 4, signed=True)
+        return jnp.asarray(fmt.plane_scales(), jnp.float32)
+    return 2.0 ** jnp.arange(n, dtype=jnp.float32)
+
+
+def _assert_matches(got, want, dtype):
+    got, want = np.asarray(got), np.asarray(want)
+    if dtype == jnp.int8:
+        np.testing.assert_array_equal(got, want)
+        return
+    # blocked accumulation reorders fp32 sums; scale atol to the output range
+    rel = 1e-5 if dtype == jnp.float32 else 2e-2
+    atol = rel * float(np.abs(want).max() + 1.0)
+    np.testing.assert_allclose(got, want, rtol=rel, atol=atol)
+
+
 # ---------------------------------------------------------------------------
 # lut_affine
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+# Bitplane shapes of both loop orders (``lut_affine.loop_order``); with
+# int8 tables: an 8-row tile walks chunks outside planes, a 128-row tile
+# planes outside chunks.
+BITPLANE_SHAPES = [
+    (8, 8, 300, 16, 300),  # 8 planes, 8-row tile, 2 windows a tile, 2 columns x 2
+    (130, 8, 20, 16, 600),  # 8 planes, 128-row tiles, 4 columns x 2 out tiles
+    (8, 1, 300, 16, 300),  # one plane
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
 @pytest.mark.parametrize(
     "B,n,k,E,p",
     [
@@ -39,19 +78,17 @@ pytestmark = pytest.mark.slow  # interpret-mode Pallas sweeps: ~45s on CPU
         (16, 11, 32, 4, 96),  # fp16-style planes (bitplane_shift tables)
         (3, 4, 130, 16, 130),  # k and p beyond one block
         (130, 2, 5, 8, 257),  # batch beyond one block, odd p
+        *BITPLANE_SHAPES,
     ],
 )
 def test_lut_affine_matches_ref(B, n, k, E, p, dtype):
     kc, kt, ks = jax.random.split(jax.random.PRNGKey(B * 7 + k), 3)
     codes = jax.random.randint(kc, (B, n, k), 0, E)
-    tables = jax.random.normal(kt, (k, E, p), dtype=jnp.float32).astype(dtype)
-    scales = 2.0 ** jnp.arange(n, dtype=jnp.float32)
+    tables = _tables(kt, (k, E, p), dtype)
+    scales = _scales(n, dtype)
     got = lut_affine(codes, tables, scales, interpret=True)
     want = lut_affine_ref(codes, tables, scales)
-    # blocked accumulation reorders fp32 sums; scale atol to the output range
-    rel = 1e-5 if dtype == jnp.float32 else 2e-2
-    atol = rel * float(np.abs(np.asarray(want)).max() + 1.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=rel, atol=atol)
+    _assert_matches(got, want, dtype)
 
 
 def test_lut_affine_leading_dims_and_bias():
@@ -88,7 +125,7 @@ def test_lut_affine_end_to_end_exact_vs_core():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
 @pytest.mark.parametrize(
     "G,B,n,k,E,p",
     [
@@ -97,24 +134,23 @@ def test_lut_affine_end_to_end_exact_vs_core():
         (2, 16, 11, 32, 4, 96),  # gate/up-style group, fp16 planes
         (4, 3, 4, 130, 16, 130),  # k and p beyond one block
         (2, 130, 2, 5, 8, 257),  # batch beyond one block, odd p
+        *[(2, *shape) for shape in BITPLANE_SHAPES],  # gate/up-style group
     ],
 )
 def test_lut_affine_grouped_matches_ref(G, B, n, k, E, p, dtype):
     kc, kt = jax.random.split(jax.random.PRNGKey(G * 13 + B * 7 + k), 2)
     codes = jax.random.randint(kc, (B, n, k), 0, E)
-    tables = jax.random.normal(kt, (G, k, E, p), dtype=jnp.float32).astype(dtype)
-    scales = 2.0 ** jnp.arange(n, dtype=jnp.float32)
+    tables = _tables(kt, (G, k, E, p), dtype)
+    scales = _scales(n, dtype)
     got = lut_affine_grouped(codes, tables, scales, interpret=True)
     want = lut_affine_grouped_ref(codes, tables, scales)
     # same slack as the ungrouped kernel: blocked fp32 accumulation order
-    rel = 1e-5 if dtype == jnp.float32 else 2e-2
-    atol = rel * float(np.abs(np.asarray(want)).max() + 1.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=rel, atol=atol)
+    _assert_matches(got, want, dtype)
     # fused grid == G separate dispatches of the per-projection kernel
     per = jnp.stack(
         [lut_affine(codes, tables[g], scales, interpret=True) for g in range(G)]
     )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(per), rtol=rel, atol=atol)
+    _assert_matches(got, per, dtype)
 
 
 def test_lut_affine_grouped_leading_dims_and_bias():
@@ -136,7 +172,7 @@ def test_lut_affine_grouped_leading_dims_and_bias():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
 @pytest.mark.parametrize(
     "E,G,T,n,k,En,p,sizes",
     [
@@ -145,19 +181,20 @@ def test_lut_affine_grouped_leading_dims_and_bias():
         (8, 1, 16, 11, 32, 4, 96, (2,) * 8),  # w_down stack, fp16 planes
         (3, 2, 130, 2, 5, 16, 129, (50, 0, 80)),  # T and p beyond one block
         (2, 2, 6, 4, 130, 16, 130, (1, 5)),  # k beyond one block, skewed
+        (2, 2, 8, 8, 300, 16, 300, (3, 5)),  # 8 planes, 8-row tile
+        (2, 1, 130, 8, 20, 16, 600, (50, 80)),  # 8 planes, 128-row tiles
+        (2, 2, 8, 1, 300, 16, 300, (5, 3)),  # one plane
     ],
 )
 def test_lut_affine_experts_matches_ref(E, G, T, n, k, En, p, sizes, dtype):
     kc, kt = jax.random.split(jax.random.PRNGKey(E * 13 + T * 7 + k), 2)
     codes = jax.random.randint(kc, (T, n, k), 0, En)
-    tables = jax.random.normal(kt, (E, G, k, En, p), dtype=jnp.float32).astype(dtype)
-    scales = 2.0 ** jnp.arange(n, dtype=jnp.float32)
+    tables = _tables(kt, (E, G, k, En, p), dtype)
+    scales = _scales(n, dtype)
     group_sizes = jnp.asarray(sizes, jnp.int32)
     got = lut_affine_experts(codes, tables, scales, group_sizes, interpret=True)
     want = lut_affine_experts_ref(codes, tables, scales, group_sizes)
-    rel = 1e-5 if dtype == jnp.float32 else 2e-2
-    atol = rel * float(np.abs(np.asarray(want)).max() + 1.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=rel, atol=atol)
+    _assert_matches(got, want, dtype)
 
 
 def test_lut_affine_experts_equals_segmented_per_expert_dispatch():
@@ -187,6 +224,27 @@ def test_lut_affine_experts_equals_segmented_per_expert_dispatch():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
     )
+
+
+def test_loop_order_tally_counts_each_traced_kernel():
+    """Every kernel traced is tallied under the loop order it compiles to:
+    chunk-outer while the per-plane partials are small (decode tiles and
+    batch tiles up to 32 rows at 8 planes and 512 lanes), plane-outer for
+    the wider tiles of admissions."""
+    from repro.kernels.lut_affine import lut_affine as kernel
+
+    assert kernel.loop_order(8, 512, 8) == "chunk_outer"
+    assert kernel.loop_order(32, 512, 8) == "chunk_outer"
+    assert kernel.loop_order(64, 512, 8) == "plane_outer"
+    assert kernel.loop_order(128, 512, 8) == "plane_outer"
+    assert kernel.loop_order(128, 512, 1) == "chunk_outer"
+    before = kernel.ORDERS_COMPILED.copy()
+    tables = jnp.zeros((3, 16, 600), jnp.int8)  # shapes no other test traces
+    scales = jnp.ones((8,), jnp.float32)
+    for rows in (8, 129):  # one 8-row tile; 128-row tiles
+        codes = jax.ShapeDtypeStruct((rows, 8, 3), jnp.int32)
+        jax.eval_shape(lambda c: lut_affine(c, tables, scales, interpret=True), codes)
+    assert kernel.ORDERS_COMPILED - before == {"chunk_outer": 1, "plane_outer": 1}
 
 
 def test_pick_blocks_respects_vmem_budget_for_groups():

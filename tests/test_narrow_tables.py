@@ -137,7 +137,14 @@ def _shift_codes(key, shape, index_bits):
     return field | (exp << index_bits)
 
 
-@pytest.mark.parametrize("B,n,k,E,p", [(4, 4, 7, 16, 10), (9, 4, 130, 16, 130)])
+@pytest.mark.parametrize(
+    "B,n,k,E,p",
+    [
+        (4, 4, 7, 16, 10),
+        (9, 4, 130, 16, 130),
+        (8, 8, 300, 16, 300),  # 8 planes over 2 windows and 2 columns
+    ],
+)
 def test_lut_affine_shift_bits_matches_ref(B, n, k, E, p):
     index_bits = 4
     assert E == 2**index_bits

@@ -25,6 +25,7 @@ from repro.kernels.lut_tl1.ops import lut_tl1, lut_tl1_grouped
 D, FF = 4096, 14336  # granite_8b d_model, d_ff
 CHUNK, ENTRIES, PLANES = 4, 16, 8  # 8-bit fixed-point bitplane, 4-element chunks
 DECODE = 8  # decode rows per dispatch
+ADMIT = 1024  # an admission's prefill rows: 8 slots x the 128-token bucket
 
 
 @pytest.fixture(scope="module")
@@ -101,13 +102,14 @@ def test_narrow_chunk_axis_compiles(one_chip, no_compile_cache, kernel):
     _assert_kernel(_compile(fn, one_chip, *shapes))
 
 
-def test_lut_affine_grouped_compiles(one_chip, no_compile_cache):
+@pytest.mark.parametrize("rows", [DECODE, ADMIT])
+def test_lut_affine_grouped_compiles(one_chip, no_compile_cache, rows):
     k = D // CHUNK  # gate/up: two 4096 -> 14336 members
     _assert_kernel(
         _compile(
             lambda c, t, s: lut_affine_grouped(c, t, s, interpret=False),
             one_chip,
-            ((DECODE, PLANES, k), jnp.int32),
+            ((rows, PLANES, k), jnp.int32),
             ((2, k, ENTRIES, FF), jnp.int8),
             ((PLANES,), jnp.float32),
         )
