@@ -2,7 +2,8 @@
 
 After the window has closed and the program's state is freed, a sample of
 the requests the window served, drawn from the seed with the longest among
-them, goes through the plain reference (``bench.reference``): once over
+them, goes through the plain reference of the configuration's
+architecture (``bench/reference/<model_type>.py``): once over
 each prompt followed by its served tokens.  At every position where the
 engine served a token, the number read is how far that token's logit lies
 below the reference's best logit there.  The widest such gap over the
@@ -19,7 +20,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.reference import llama as ref
+from bench import spec
 
 
 def pick(sent: list, n: int, seed: int, stream) -> list:
@@ -62,6 +63,7 @@ def widest_gap(c: dict, seed: int, tokens, targets, mask) -> tuple[float, int]:
     the number of served tokens outside the vocabulary."""
     bad = int(np.sum(mask & ((targets < 0) | (targets >= c["vocab_size"]))))
     t = np.where(mask, np.clip(targets, 0, c["vocab_size"] - 1), 0)
+    ref = spec.reference(c)
     h = ref.hidden(c, seed, tokens)
     best, at, _ = ref.head_stats(c, seed, h, t)
     gap = np.asarray(best) - np.asarray(at)
@@ -71,6 +73,7 @@ def widest_gap(c: dict, seed: int, tokens, targets, mask) -> tuple[float, int]:
 def control_targets(c: dict, seed: int, tokens) -> np.ndarray:
     """At every position, the token that the reference one precision step
     below the configuration's ranks first."""
+    ref = spec.reference(c)
     low = ref.hidden(c, seed, tokens, lower=True)
     _, _, top = ref.head_stats(c, seed, low, np.zeros(tokens.shape, np.int32))
     return np.asarray(top)

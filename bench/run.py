@@ -137,16 +137,16 @@ def setup(cell, seed: int, peaks: dict):
     import numpy as np
 
     from bench import families, traffic, weights
-    from bench.archs import llama
     from repro.models.layers import Ctx
     from repro.serve import BatchingEngine, Request
 
     c, mix = cell.config, cell.traffic
     traffic.check(mix)
-    mcfg = llama.program_config(c)
+    arch = spec.arch(c)
+    mcfg = arch.program_config(c)
     fam = importlib.import_module(f"bench.families.{c['family']}")
     t = time.perf_counter()
-    dense = weights.make(llama.weight_layout(c), c["num_hidden_layers"], seed)
+    dense = weights.make(arch.weight_layout(c), c["num_hidden_layers"], seed)
     jax.block_until_ready(dense)
     init_s = time.perf_counter() - t
     params, ex, info = fam.build(dense, mcfg, c, cache_bytes(mcfg, mix),
@@ -158,7 +158,7 @@ def setup(cell, seed: int, peaks: dict):
         init_s=init_s,
         stored_bytes=families.stored_bytes(params),
         row_bytes=fam.row_bytes(params),
-        linear_work=llama.linear_work(c),
+        linear_work=arch.linear_work(c),
     )
     t = time.perf_counter()
     engine = BatchingEngine(
