@@ -5,7 +5,7 @@ from __future__ import annotations
 import math
 import time
 
-from bench.families import exec_cfg, table_nodes
+from bench.families import exec_cfg, routed_rows
 
 
 def build(dense, cfg, c: dict, cache_bytes: int, hbm_bytes: float):
@@ -15,9 +15,10 @@ def build(dense, cfg, c: dict, cache_bytes: int, hbm_bytes: float):
     conf = c["tl1"]
     t0 = time.perf_counter()
     mplan = plan_model(
-        dense, math.inf, families=("tl1",), tl1_act_bits=conf["act_bits"]
+        dense, math.inf, families=("tl1",), tl1_act_bits=conf["act_bits"],
+        convert_experts=True,
     )
-    params, report = convert_params(dense, plan=mplan)
+    params, report = convert_params(dense, plan=mplan, convert_experts=True)
     if report.converted != c["converted_linears"]:
         raise SystemExit(
             f"tl1 converted {report.converted} projections, "
@@ -30,12 +31,15 @@ def build(dense, cfg, c: dict, cache_bytes: int, hbm_bytes: float):
     }
 
 
-def row_bytes(params) -> int:
-    """int32 activation codes and scale in, float32 outputs out, per row."""
+def row_bytes(params, cfg) -> int:
+    """int32 activation codes and scale in, float32 outputs out, once for
+    each row a node computes (``routed_rows``)."""
     total = 0
-    for n in table_nodes(params):
+    for n, rows in routed_rows(params, cfg):
         layers = n.tables.shape[0]
         members = len(getattr(n, "members", ("one",)))
         plan = n.plan
-        total += layers * (4 * plan.padded_in + 4 + 4 * members * plan.out_features)
+        total += rows * layers * (
+            4 * plan.padded_in + 4 + 4 * members * plan.out_features
+        )
     return total

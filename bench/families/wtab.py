@@ -5,13 +5,13 @@ point codes with ``act_frac`` fractional bits, bitplane mode, tables of at
 most ``MAX_SELECT_ENTRIES`` entries stored as ``table_format`` integers,
 planned under the HBM left beside the dense parameters, the cache and
 ``HEADROOM``.  Set-up refuses a plan that differs from the configuration
-file or leaves a projection dense.
+file, routed experts' entries among them, or leaves a projection dense.
 """
 from __future__ import annotations
 
 import time
 
-from bench.families import exec_cfg, table_nodes
+from bench.families import exec_cfg, routed_rows
 
 HEADROOM = 4 * 2**30  # HBM kept free of tables: activations, temporaries
 
@@ -32,6 +32,7 @@ def _plan(dense, cfg, conf, cache_bytes, hbm_bytes):
         modes=(conf["mode"],),
         table_formats=(conf["table_format"],),
         max_entries=MAX_SELECT_ENTRIES,
+        convert_experts=True,
     )
 
 
@@ -42,7 +43,7 @@ def build(dense, cfg, c: dict, cache_bytes: int, hbm_bytes: float):
     conf = c["wtab"]
     t0 = time.perf_counter()
     mplan = _plan(dense, cfg, conf, cache_bytes, hbm_bytes)
-    params, report = convert_params(dense, plan=mplan)
+    params, report = convert_params(dense, plan=mplan, convert_experts=True)
     for path, p in mplan.layers.items():
         got = (p.chunk_size, p.mode, p.table_format, p.fmt.total_bits, p.fmt.frac_bits)
         want = (conf["chunk"], conf["mode"], conf["table_format"],
@@ -61,15 +62,16 @@ def build(dense, cfg, c: dict, cache_bytes: int, hbm_bytes: float):
     }
 
 
-def row_bytes(params) -> int:
+def row_bytes(params, cfg) -> int:
     """Codes read and outputs written per token row, over every dispatch:
-    int32 codes of each bitplane and chunk, float32 outputs."""
+    int32 codes of each bitplane and chunk, float32 outputs, once for each
+    row a node computes (``routed_rows``)."""
     total = 0
-    for n in table_nodes(params):
+    for n, rows in routed_rows(params, cfg):
         layers = n.tables.shape[0]
         members = len(getattr(n, "members", ("one",)))
         plan = n.plan
-        total += layers * (
+        total += rows * layers * (
             4 * plan.num_planes * plan.num_chunks + 4 * members * plan.out_features
         )
     return total

@@ -60,6 +60,7 @@ class Run:
     info: dict
     peaks: dict
     slots: int
+    config: dict  # the cell's configuration file
     events: object = None  # trace.Events, --trace 1 only
 
     @property
@@ -146,7 +147,7 @@ def setup(cell, seed: int, peaks: dict):
     mcfg = arch.program_config(c)
     fam = importlib.import_module(f"bench.families.{c['family']}")
     t = time.perf_counter()
-    dense = weights.make(arch.weight_layout(c), c["num_hidden_layers"], seed)
+    dense = weights.make(arch.weight_layout(c), spec.stacks(c), seed)
     jax.block_until_ready(dense)
     init_s = time.perf_counter() - t
     params, ex, info = fam.build(dense, mcfg, c, cache_bytes(mcfg, mix),
@@ -157,7 +158,8 @@ def setup(cell, seed: int, peaks: dict):
     info.update(
         init_s=init_s,
         stored_bytes=families.stored_bytes(params),
-        row_bytes=fam.row_bytes(params),
+        expert_stored_bytes=families.nbytes(families.expert_nodes(params)),
+        row_bytes=fam.row_bytes(params, mcfg),
         linear_work=arch.linear_work(c),
     )
     t = time.perf_counter()
@@ -203,7 +205,8 @@ def measure(cell, seed: int, seconds: float, trace_on: bool, peaks: dict,
     gc.callbacks.append(timer)
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f} s: " + json.dumps(
-        {k: info[k] for k in ("init_s", "convert_s", "warmup_s", "plan")}))
+        {k: info[k] for k in ("init_s", "convert_s", "warmup_s", "plan", "stored_bytes",
+                              "expert_stored_bytes", "row_bytes")}))
 
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
@@ -241,7 +244,7 @@ def measure(cell, seed: int, seconds: float, trace_on: bool, peaks: dict,
         "prefill_tokens": loop.prefilled_in(rec, t1),
         "prompt_tokens_admitted": loop.admitted_in(rec, t0, t1),
     }
-    run = Run(rec, t0, t1, limit, setup_s, counters, info, peaks, mix["slots"])
+    run = Run(rec, t0, t1, limit, setup_s, counters, info, peaks, mix["slots"], c)
     d0 = jax.devices()[0]
     device = {"platform": d0.platform, "kind": d0.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": int(peak)}

@@ -6,7 +6,8 @@ Its metrics are the ``end_to_end`` and ``per_layer`` entries that list it
 under ``workloads`` or list no cells; each is read by
 ``bench/e2e/<name>.py`` or ``bench/metrics/<name>.py``.  A configuration
 names its architecture by the published ``model_type``, served as
-``bench/archs/<model_type>.py`` and checked against
+``bench/archs/<model_type>.py``, whose layers stack by kind as its
+``stacks`` says (:func:`stacks`), and checked against
 ``bench/reference/<model_type>.py``, and its execution family by
 ``family``, set up by ``bench/families/<family>.py``.  Adding a cell, mix,
 configuration, architecture or metric adds files and entries; no code
@@ -51,8 +52,9 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     w = cells[name]
     entry = {c["name"]: c for c in bm["configs"]}[w["config"]]
     config = dict(load_json(os.path.join(root, entry["file"])), name=entry["name"])
-    arch(config)  # a missing architecture or reference ends the run here,
-    reference(config)  # before any phase
+    arch(config)  # a missing architecture or reference, or stacks that do
+    reference(config)  # not make up the model, end the run here, before
+    stacks(config)  # any phase
     traffic = dict(
         load_json(os.path.join(root, "bench", "traffic", w["traffic"] + ".json")),
         name=w["traffic"],
@@ -101,8 +103,27 @@ def _by_model_type(kind: str, c: dict):
 
 def arch(c: dict):
     """The architecture module of configuration ``c``: ``program_config``,
-    ``weight_layout``, ``linear_work`` (``bench/archs/__init__.py``)."""
+    ``weight_layout``, ``linear_work``, and maybe ``stacks``
+    (``bench/archs/__init__.py``)."""
     return _by_model_type("archs", c)
+
+
+def stacks(c: dict) -> dict:
+    """``{prefix: depth}`` of the layer stacks of configuration ``c``: the
+    architecture's ``stacks(c)``, or one stack ``blocks`` of every layer.
+    Depths that do not add up to ``num_hidden_layers``, or a prefix that
+    starts another, end the run."""
+    a = arch(c)
+    got = a.stacks(c) if hasattr(a, "stacks") else {"blocks": c["num_hidden_layers"]}
+    if (sum(got.values()) != c["num_hidden_layers"]
+            or any(d < 1 for d in got.values())):
+        raise SystemExit(f"bench: stacks {got} do not make up the "
+                         f"{c['num_hidden_layers']} layers of {c.get('name')}")
+    for p in got:
+        for q in got:
+            if p != q and q.startswith(p):
+                raise SystemExit(f"bench: stack prefix {p!r} starts {q!r}")
+    return got
 
 
 def reference(c: dict):

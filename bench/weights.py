@@ -1,9 +1,12 @@
 """Dense weights from ``--seed``, made on the device in one jitted call.
 
-Leaf ``j`` of the sorted layout draws from ``fold_in(key, j)``, and layer
-``i`` of a block leaf from ``fold_in(fold_in(key, j), i)``, so one layer
-can be made again on its own (:func:`layer`), value for value, by the
-reference after the window.  Nothing here imports the program.
+The layers of a model sit in stacks, ``{prefix: depth}``
+(``spec.stacks``): every leaf under ``<prefix>/`` is one layer's, and the
+tree holds it ``depth`` deep.  Leaf ``j`` of the sorted layout draws from
+``fold_in(key, j)``, and layer ``i`` of a stacked leaf from
+``fold_in(fold_in(key, j), i)``, so one layer can be made again on its
+own (:func:`layer`), value for value, by the reference after the window.
+Nothing here imports the program.
 """
 from __future__ import annotations
 
@@ -37,35 +40,41 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "layers", "dtype"))
-def _make(key, layout, layers, dtype):
+def _stack_of(path: str, prefixes) -> str | None:
+    return next((p for p in prefixes if path.startswith(p + "/")), None)
+
+
+@functools.partial(jax.jit, static_argnames=("layout", "stacks", "dtype"))
+def _make(key, layout, stacks, dtype):
+    depth = dict(stacks)
     flat = {}
     for j, (path, shape, init) in enumerate(layout):
         k = jax.random.fold_in(key, j)
-        if path.startswith("blocks/"):
-            ks = jax.vmap(lambda i: jax.random.fold_in(k, i))(jnp.arange(layers))
+        prefix = _stack_of(path, depth)
+        if prefix is not None:
+            ks = jax.vmap(lambda i: jax.random.fold_in(k, i))(jnp.arange(depth[prefix]))
             flat[path] = jax.vmap(lambda kk: _leaf(kk, shape, init, dtype))(ks)
         else:
             flat[path] = _leaf(k, shape, init, dtype)
     return _nest(flat)
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "dtype"))
-def _make_layer(key, i, layout, dtype):
+@functools.partial(jax.jit, static_argnames=("layout", "prefix", "dtype"))
+def _make_layer(key, i, layout, prefix, dtype):
     flat = {}
     for j, (path, shape, init) in enumerate(layout):
-        if path.startswith("blocks/"):
+        if path.startswith(prefix + "/"):
             k = jax.random.fold_in(jax.random.fold_in(key, j), i)
-            flat[path[len("blocks/"):]] = _leaf(k, shape, init, dtype)
+            flat[path[len(prefix) + 1:]] = _leaf(k, shape, init, dtype)
     return _nest(flat)
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "dtype"))
-def _make_top(key, layout, dtype):
+@functools.partial(jax.jit, static_argnames=("layout", "stacks", "dtype"))
+def _make_top(key, layout, stacks, dtype):
     return _nest({
         path: _leaf(jax.random.fold_in(key, j), shape, init, dtype)
         for j, (path, shape, init) in enumerate(layout)
-        if not path.startswith("blocks/")
+        if _stack_of(path, stacks) is None
     })
 
 
@@ -73,16 +82,20 @@ def _frozen(layout: dict) -> tuple:
     return tuple((p, tuple(s), i) for p, (s, i) in sorted(layout.items()))
 
 
-def make(layout: dict, layers: int, seed: int, dtype=jnp.bfloat16) -> dict:
-    """The whole tree, block leaves stacked ``(layers, ...)``."""
-    return _make(base_key(seed), _frozen(layout), layers, dtype)
+def make(layout: dict, stacks: dict, seed: int, dtype=jnp.bfloat16) -> dict:
+    """The whole tree, each leaf under ``<prefix>/`` stacked
+    ``(stacks[prefix], ...)``."""
+    return _make(base_key(seed), _frozen(layout), tuple(sorted(stacks.items())), dtype)
 
 
-def layer(layout: dict, seed: int, i: int, dtype=jnp.bfloat16) -> dict:
-    """Block ``i``'s leaves, equal to ``make(...)["blocks"][...][i]``."""
-    return _make_layer(base_key(seed), jnp.int32(i), _frozen(layout), dtype)
+def layer(layout: dict, seed: int, i: int, prefix: str = "blocks",
+          dtype=jnp.bfloat16) -> dict:
+    """Layer ``i`` of stack ``prefix``, equal to
+    ``make(...)[prefix][...][i]``."""
+    return _make_layer(base_key(seed), jnp.int32(i), _frozen(layout), prefix, dtype)
 
 
-def top(layout: dict, seed: int, dtype=jnp.bfloat16) -> dict:
-    """The leaves outside the blocks (embedding, final norm)."""
-    return _make_top(base_key(seed), _frozen(layout), dtype)
+def top(layout: dict, seed: int, stacks=("blocks",), dtype=jnp.bfloat16) -> dict:
+    """The leaves outside every stack (embedding, final norm, head);
+    ``stacks`` holds the prefixes (a ``{prefix: depth}`` will do)."""
+    return _make_top(base_key(seed), _frozen(layout), tuple(sorted(stacks)), dtype)
